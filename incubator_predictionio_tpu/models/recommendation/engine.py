@@ -792,12 +792,14 @@ class ALSAlgorithm(Algorithm):
 
         # the SAME ladder the scheduler can dispatch (ops/topk
         # ladder_rungs — one rule, shared, so warmed shapes cannot
-        # drift from dispatchable shapes). Rung 1 is skipped: the
-        # scheduler routes singleton batches through predict(), so B=1
-        # is a batched shape live traffic never produces
+        # drift from dispatchable shapes). Rung 1 included: a lone plain
+        # query rides the columnar fast path (batch_serve_json →
+        # batch_score_top_k at B=1), not predict(). Skipped, its program
+        # compiled on the first live query — 0.2–0.3 s on a v5e — and
+        # that one wall pushed the latency histogram's p99 over the
+        # serve SLO, so the scheduler shed the next burst with 503s
+        # (seen on the chip, PERF.md PR 21)
         for size in ladder_rungs(int(max_batch)):
-            if size < 2:
-                continue
             self.batch_predict(model, [(i, q) for i in range(size)])
 
     def _pack_scores(self, model: ALSModel, scores, indices) -> PredictedResult:

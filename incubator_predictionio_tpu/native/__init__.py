@@ -12,13 +12,16 @@ XLA/Pallas; the *runtime around it* is native where it is hot:
 The shared library is compiled on first use with the system ``g++`` (no pip
 deps, mirroring how the reference compiles engines on demand via ``pio
 build`` → sbt, tools/.../commands/Engine.scala:158-225) and cached next to
-the sources keyed on their mtimes. Everything degrades gracefully: callers
-check :func:`load` for ``None`` and fall back to pure-Python paths.
+the sources, keyed by a digest of their CONTENT (never mtimes). Callers
+check :func:`load` for ``None`` and fall back to pure-Python paths — fine
+for tests and small stores, a hang at 20M events: ``chip_smoke.py`` fails
+its *native* phase when the library does not build and load.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -37,39 +40,49 @@ _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 
 
+def _compile_cmd() -> list:
+    return [os.environ.get("CXX", "g++"),
+            "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+
+
+def _source_digest() -> str:
+    """sha256 over the compile command and every source's bytes — the
+    build key. Content, not mtime: a copied or checked-out tree (whose
+    mtimes say nothing) can never load a library built from other
+    sources."""
+    h = hashlib.sha256(" ".join(_compile_cmd()).encode())
+    for name in _SOURCES:
+        h.update(name.encode())
+        h.update((_SRC_DIR / name).read_bytes())
+    return h.hexdigest()[:16]
+
+
 def lib_path() -> Path:
-    return _BUILD_DIR / "libpio_native.so"
-
-
-def _needs_build(so: Path) -> bool:
-    if not so.exists():
-        return True
-    so_mtime = so.stat().st_mtime
-    return any(
-        (_SRC_DIR / s).stat().st_mtime > so_mtime for s in _SOURCES
-    )
+    return _BUILD_DIR / f"libpio_native-{_source_digest()}.so"
 
 
 def build(force: bool = False) -> Path:
-    """Compile the native library (idempotent; mtime-cached)."""
+    """Compile the native library (idempotent; keyed by the digest of
+    the sources in its file name)."""
     so = lib_path()
-    if not force and not _needs_build(so):
+    if not force and so.exists():
         return so
     _BUILD_DIR.mkdir(parents=True, exist_ok=True)
     # compile to a process-unique temp name, then atomically rename: two
     # processes racing a cold build must never CDLL a half-written .so
     tmp = so.with_suffix(f".so.tmp{os.getpid()}")
-    cmd = [
-        os.environ.get("CXX", "g++"),
-        "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        *[str(_SRC_DIR / s) for s in _SOURCES],
-        "-o", str(tmp),
-    ]
+    cmd = [*_compile_cmd(), *[str(_SRC_DIR / s) for s in _SOURCES],
+           "-o", str(tmp)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
         os.replace(tmp, so)
     finally:
         tmp.unlink(missing_ok=True)
+    # libraries of other source digests are dead weight (a process that
+    # still has one mapped keeps its inode)
+    for old in _BUILD_DIR.glob("libpio_native*.so"):
+        if old != so:
+            old.unlink(missing_ok=True)
     return so
 
 

@@ -12,9 +12,9 @@ default and enabled with ``PIO_PROFILE=1``:
   is *not* counted — it shows up as lower MFU, the honest convention
   the bench uses),
 - ``pio_mfu{phase}`` — the LAST dispatch's model-FLOP utilization in
-  that phase against the fp32 peak (``PIO_BENCH_PEAK_FLOPS``, same
-  convention as the bench record's ``mfu``), so the live gauge and the
-  bench's offline figure are directly comparable.
+  that phase against the device's published peak (:data:`PEAK_FLOPS`,
+  one table keyed by ``device_kind``). A device the table does not
+  list has no peak: the gauge stays unset — never a default.
 
 Op labels are a BOUNDED set chosen by the call sites: ``als_train``
 (XLA-assembly training), ``als_fused`` (training through the fused
@@ -65,8 +65,20 @@ DEVICE_FLOPS = obs_metrics.REGISTRY.counter(
     labels=("op",))
 MFU = obs_metrics.REGISTRY.gauge(
     "pio_mfu",
-    "last attributed dispatch's model-FLOP utilization vs the fp32 "
-    "peak (PIO_BENCH_PEAK_FLOPS), by phase", labels=("phase",))
+    "last attributed dispatch's model-FLOP utilization vs the device's "
+    "published peak (obs/profile.py PEAK_FLOPS; unset on a device the "
+    "table does not list), by phase", labels=("phase",))
+
+#: published peak dense-matmul FLOP/s of ONE chip, keyed by
+#: ``jax.devices()[0].device_kind``, each with its source. The only
+#: place a peak lives; a device that is not here has none.
+PEAK_FLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    # (16 GB HBM at 819 GB/s). jax reports the chip as "TPU v5 lite".
+    "TPU v5 lite": 197e12,
+}
+
+_no_peak_logged: set = set()
 
 
 def enabled() -> bool:
@@ -77,15 +89,20 @@ def enabled() -> bool:
         "0", "", "false", "off")
 
 
-def peak_flops() -> float:
-    """The fp32 peak the MFU gauge divides by — the SAME knob the bench
-    uses (``PIO_BENCH_PEAK_FLOPS``, default TPU v5e ~98.5 TF/s fp32) so
-    ``pio_mfu{phase="train"}`` and the record's ``mfu`` are the same
-    convention by construction."""
-    try:
-        return float(os.environ.get("PIO_BENCH_PEAK_FLOPS", "") or 98.5e12)
-    except ValueError:
-        return 98.5e12
+def peak_flops() -> Optional[float]:
+    """The peak ``pio_mfu`` divides by: :data:`PEAK_FLOPS` for this
+    process's ``device_kind``, or None — logged once per kind — when
+    the table has no entry (the CPU backend among them)."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    peak = PEAK_FLOPS.get(kind)
+    if peak is None and kind not in _no_peak_logged:
+        _no_peak_logged.add(kind)
+        logger.warning(
+            "no published peak for device_kind %r in obs/profile.py "
+            "PEAK_FLOPS: pio_mfu stays unset", kind)
+    return peak
 
 
 def t0() -> Optional[float]:
@@ -128,8 +145,9 @@ def record(start: Optional[float], phase: str, op: str,
         DEVICE_DISPATCHES.labels(op=op).inc()
         if flops > 0:
             DEVICE_FLOPS.labels(op=op).inc(flops)
-            if dt > 0:
-                MFU.labels(phase=phase).set(flops / dt / peak_flops())
+            peak = peak_flops()
+            if dt > 0 and peak:
+                MFU.labels(phase=phase).set(flops / dt / peak)
     except Exception:
         logger.exception("dispatch profiler record failed (op=%s)", op)
 

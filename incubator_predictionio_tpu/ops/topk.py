@@ -23,6 +23,18 @@ from incubator_predictionio_tpu.ops import mips as _mips
 
 NEG_INF = jnp.float32(-3.4e38)
 
+#: precision of every exhaustive scoring matmul here. The factors are
+#: f32 and the answers are compared with — and must rank like — plain f32
+#: arithmetic (the host mirror, the byte-identity contract between the
+#: serving paths). A TPU's DEFAULT precision multiplies f32 operands in
+#: ONE bf16 pass: measured on a v5e at ML-20M shape (PERF.md, PR 21),
+#: the batched [B, K]·[K, I] dispatch then returned a different top-10
+#: for 16 of 64 users and scores off by up to 2.4e-3 relative; HIGHEST
+#: gave 64 of 64 and 4.5e-7. (The matvec paths were already exact there;
+#: pinned all the same so the paths cannot drift apart. On the CPU
+#: backend the argument changes nothing.)
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def top_k_with_exclusions(
@@ -49,7 +61,7 @@ def _score_and_top_k_xla(
     exclude: Optional[jax.Array] = None,
     allowed_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
-    scores = item_factors @ user_vector
+    scores = jnp.dot(item_factors, user_vector, precision=_EXACT)
     top_s, top_i = top_k_with_exclusions(scores, k, exclude, allowed_mask)
     return jnp.stack([top_s, top_i.astype(jnp.float32)])
 
@@ -124,7 +136,7 @@ def _sharded_topk_jit(
         ex_l = rest.pop(0) if has_ex else None
         mask_l = rest.pop(0) if has_mask else None
         offset = axis_index(axes) * local_rows
-        scores = items_l @ uv_l                      # [local_rows]
+        scores = jnp.dot(items_l, uv_l, precision=_EXACT)  # [local_rows]
         rows_g = offset + jnp.arange(local_rows)
         scores = jnp.where(rows_g < valid_items, scores, NEG_INF)
         if mask_l is not None:
@@ -144,7 +156,7 @@ def _sharded_topk_jit(
 
     return shard_map(
         shard, mesh=mesh, in_specs=tuple(specs),
-        out_specs=P(), check_rep=False,
+        out_specs=P(), check_vma=False,
     )(*args)
 
 
@@ -205,7 +217,8 @@ def _score_user_top_k_xla(
     exclude: Optional[jax.Array] = None,
     allowed_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
-    scores = item_factors @ user_factors[user_idx]
+    scores = jnp.dot(item_factors, user_factors[user_idx],
+                     precision=_EXACT)
     top_s, top_i = top_k_with_exclusions(scores, k, exclude, allowed_mask)
     return jnp.stack([top_s, top_i.astype(jnp.float32)])
 
@@ -222,9 +235,8 @@ def score_user_and_top_k(
     """Serving fast path: user-row gather + full-catalog scoring + top-k in
     ONE device call, packed [2, k].
 
-    On a tunneled/remote TPU every separate op is a host round trip;
-    indexing ``user_factors[user_idx]`` outside the jit would double the
-    per-query latency. Callers fetch the packed result with one
+    Indexing ``user_factors[user_idx]`` outside the jit would be a second
+    dispatch per query. Callers fetch the packed result with one
     ``np.asarray``. ``valid_items`` masks trailing padding rows — a
     PLACED table's pow2 capacity tail has zero factors, and score 0
     would outrank genuinely negative real items — so any caller serving
@@ -295,7 +307,8 @@ def _batch_score_top_k_xla(
     k: int,
     valid_items: Optional[int] = None,
 ) -> jax.Array:
-    scores = user_factors[rows] @ item_factors.T          # [B, I] — MXU
+    scores = jnp.dot(user_factors[rows], item_factors.T,
+                     precision=_EXACT)                    # [B, I] — MXU
     if valid_items is not None and valid_items < item_factors.shape[0]:
         # placed tables carry zero-factor padding rows; mask them out
         # (score 0 would outrank genuinely negative real items). Under
@@ -422,9 +435,9 @@ def score_and_top_k(
     """Full-catalog scoring + ranking in one fused device call.
 
     Returns a single packed [2, k] f32 array (row 0 = scores, row 1 =
-    indices): serving pays exactly ONE device→host fetch per query — on a
-    tunneled/remote TPU each fetch is a full round trip, so fetch count, not
-    FLOPs, dominates query latency. Large catalogs on real TPU route to the
+    indices): serving pays exactly ONE device→host fetch per query — at
+    27k items the fetch count, not the FLOPs, sets the query latency.
+    Large catalogs on a TPU route to the
     Pallas blocked-candidate kernel (ops/pallas_kernels.py), which never
     writes the full score vector to HBM. ``valid_items`` masks a placed
     table's zero-factor padding tail (see :func:`score_user_and_top_k`).

@@ -16,53 +16,20 @@ no-ops (or cheap copies) when the axis has size 1.
 
 from __future__ import annotations
 
-import functools
-import inspect
 from typing import Any, Sequence, Union
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map  # noqa: F401 - re-exported: THE import the framework uses
 
 AxisName = Union[str, Sequence[str]]
 
-try:  # jax ≥ 0.6 exports it at top level
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # the long-stable experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-#: which replication-check kwarg the installed jax understands
-#: (renamed check_rep → check_vma upstream)
-_SM_PARAMS = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f=None, **kwargs):
-    """Version-stable ``shard_map`` — THE import the framework uses.
-
-    Papers over the two upstream API moves that would otherwise pin the
-    repo to one jax version: the export location (``jax.shard_map`` vs
-    ``jax.experimental.shard_map``) and the replication-check kwarg
-    rename (``check_rep`` → ``check_vma``). Callers may pass either
-    spelling; it is translated to whatever the installed jax accepts.
-    """
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    rep = kwargs.pop("check_rep", kwargs.pop("check_vma", None))
-    if rep is not None:
-        kwargs["check_vma" if "check_vma" in _SM_PARAMS
-               else "check_rep"] = rep
-    return _shard_map_impl(f, **kwargs)
-
 
 def axis_size(axis_name: AxisName) -> int:
-    """Number of shards along ``axis_name`` (inside shard_map).
-
-    ``lax.psum`` of the constant 1 folds to the axis size AT TRACE TIME
-    (a python int, usable for static permutation tables) on every jax
-    version; ``lax.axis_size`` only exists on newer ones."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Number of shards along ``axis_name`` (inside shard_map) — a python
+    int at trace time, usable for static permutation tables."""
+    return lax.axis_size(axis_name)
 
 
 def axis_index(axis_name: AxisName):

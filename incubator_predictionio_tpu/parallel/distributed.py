@@ -50,8 +50,7 @@ def ensure_initialized() -> bool:
     n_proc = int(os.environ.get("PIO_NUM_PROCESSES", "1") or 1)
     if coord and n_proc <= 1:
         # a 1-host pod has nothing to coordinate: plain single-controller
-        # JAX is the correct runtime (and distributed.initialize with a
-        # 1-process service hangs under proxied/tunneled device platforms)
+        # JAX is the correct runtime
         logger.info("distributed: single process — coordinator skipped")
         coord = None
     if coord:

@@ -43,8 +43,8 @@ workers:
 
 - **Elastic join.** Workers announce PORT only after their pow2 ladder
   is warm (tests/fleet_worker.py), and the shared persistent XLA
-  compile cache (utils/compile_cache.py, ``PIO_COMPILE_CACHE`` at a
-  fleet-shared directory) turns that warmup from a compile wall into a
+  compile cache (utils/compile_cache.py, ``JAX_COMPILATION_CACHE_DIR``
+  at a fleet-shared directory) turns that warmup from a compile wall into a
   disk read — join-to-first-dispatch is seconds, measured by
   ``bench.py bench_frontdoor`` as ``frontdoor_join_to_first_dispatch_s``
   with the cold/warm delta recorded.

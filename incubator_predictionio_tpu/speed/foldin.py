@@ -178,12 +178,13 @@ class FoldInSolver:
         self.alpha = float(alpha)
         self.cg_iters = int(cg_iters if cg_iters is not None
                             else _als._CG_ITERS)
-        # fused-kernel routing, resolved ONCE per deploy (the Mosaic
-        # probe compiles a real kernel — never per fold-in): the ladder
+        # fused-kernel routing, resolved ONCE per deploy: the ladder
         # buckets dispatch the SAME fused gather+Gram+CG kernel training
         # uses, when the frozen table fits its VMEM budget. None = auto
-        # (PIO_ALS_FUSED_GRAM + per-variant probe); tests force True,
-        # which serves via interpret on Mosaic-less backends.
+        # (PIO_ALS_FUSED_GRAM — off unless forced: the kernel does not
+        # lower on the installed TPU compiler, ops/als.py
+        # _fused_enabled); tests force True, which serves via interpret
+        # on the CPU backend.
         from incubator_predictionio_tpu.ops.pallas_kernels import (
             als_fused_fits,
         )

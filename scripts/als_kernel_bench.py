@@ -28,10 +28,8 @@ def main() -> int:
 
     import jax
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    # dial as a killable waiter, then make SIGTERM a clean exit so a
-    # timeout-kill mid-run cannot wedge the lease we now hold
+    # SIGTERM → normal interpreter shutdown while this process holds
+    # the chip (utils/lease.py)
     jax.devices()
     install_sigterm_exit()
 

@@ -24,8 +24,8 @@ def main():
 
     import jax
 
-    # dial as a killable waiter, then make SIGTERM a clean exit so a
-    # timeout-kill mid-run cannot wedge the lease we now hold
+    # SIGTERM → normal interpreter shutdown while this process holds
+    # the chip (utils/lease.py)
     jax.devices()
     install_sigterm_exit()
     import jax.numpy as jnp

@@ -1,14 +1,15 @@
 """Split the warm-process compile cost into trace/lower vs cache-hit
 compile (dev tool for the persistent-cache numbers in BENCH/BASELINE).
 
-Phase 1 (fresh cache dir): lower + compile cold, writing the cache entry.
+Phase 1: lower + compile, writing the cache entry (cold only when the
+resolved cache directory — JAX_COMPILATION_CACHE_DIR, else
+<checkout>/.xla_cache — holds none for this program yet).
 Phase 2 (jax.clear_caches): lower again (pure Python/trace cost), then
 compile — which should be a persistent-cache HIT (deserialize only).
-Run on the real TPU: python scripts/compile_cache_profile.py [nnz]
+Run on the chip: python scripts/compile_cache_profile.py [nnz]
 """
 import os
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -24,8 +25,8 @@ def main():
 
     import jax
 
-    # dial as a killable waiter, then make SIGTERM a clean exit so a
-    # timeout-kill mid-run cannot wedge the lease we now hold
+    # SIGTERM → normal interpreter shutdown while this process holds
+    # the chip (utils/lease.py)
     jax.devices()
     install_sigterm_exit()
     import jax.numpy as jnp
@@ -37,8 +38,8 @@ def main():
     )
     from incubator_predictionio_tpu.utils import compile_cache
 
-    cache_dir = tempfile.mkdtemp(prefix="pio_ccprof_")
-    compile_cache.enable(cache_dir)
+    compile_cache.enable()
+    cache_dir = compile_cache.cache_dir()
 
     rng = np.random.default_rng(7)
     iw = (np.arange(N_ITEMS) + 1.0) ** -0.55
@@ -62,7 +63,7 @@ def main():
     def lower():
         return als._als_run_fused.lower(state, u_tree, i_tree, **kwargs)
 
-    for phase in ("cold", "warm-cache"):
+    for phase in ("first", "warm-cache"):
         if phase == "warm-cache":
             jax.clear_caches()
         t0 = time.perf_counter()
@@ -73,7 +74,6 @@ def main():
         t_compile = time.perf_counter() - t0
         print(f"{phase:11s} trace+lower={t_lower:5.1f}s "
               f"compile={t_compile:5.1f}s", flush=True)
-    import os
     sizes = sum(
         os.path.getsize(os.path.join(cache_dir, f))
         for f in os.listdir(cache_dir))

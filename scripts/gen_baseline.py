@@ -85,7 +85,7 @@ def render(path: Path) -> str:
             row("heldout RMSE (fresh draws)", "heldout_rmse"),
             row("planted noise floor", "noise_floor"),
             row("precision@10 vs true ranking", "precision_at_10_vs_truth"),
-            row("compile cold", "compile_s_cold", " s"),
+            row("compile first call", "compile_s_first", " s"),
             row("compile warm persistent cache", "compile_s_warm_cache",
                 " s"),
         ]),

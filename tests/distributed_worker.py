@@ -30,8 +30,6 @@ import numpy as np  # noqa: E402
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 from incubator_predictionio_tpu.parallel import distributed  # noqa: E402
 
 # jax.distributed.initialize must run before ANYTHING touches the XLA

@@ -23,10 +23,12 @@ Run as a REAL separate process by tests/test_federation.py and by
   model through the real warm-before-swap route (what the front door's
   rolling reload drives).
 
-``--compile-cache DIR`` points the persistent XLA compile cache at a
-FLEET-SHARED directory (utils/compile_cache.py) before any jax work, so
-a joining worker pre-warms its pow2 ladder from disk instead of paying
-the cold compile wall — the elasticity story bench_frontdoor measures.
+``--compile-cache`` enables the persistent XLA compile cache before any
+jax work, at the FLEET-SHARED directory utils/compile_cache.py resolves
+(``JAX_COMPILATION_CACHE_DIR`` if the parent exports one, else the
+in-checkout default), so a joining worker pre-warms its pow2 ladder from
+disk instead of paying the cold compile wall — the elasticity story
+bench_frontdoor measures.
 
 ``--chaos SPEC`` arms fault injection (comma-separated; serve mode):
 
@@ -351,9 +353,11 @@ def main() -> None:
                     help="pad every scheduler dispatch to this wall — "
                          "the CPU sim's stand-in for an accelerator's "
                          "fixed per-dispatch cost (serve mode)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="fleet-shared persistent XLA compile cache "
-                         "directory (serve mode join pre-warm)")
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="enable the persistent XLA compile cache at "
+                         "the fleet-shared directory utils/"
+                         "compile_cache.py resolves (serve mode join "
+                         "pre-warm)")
     ap.add_argument("--chaos", default="",
                     help="fault injection: kill-after=S, stall-after=S, "
                          "latency-spike=MS:P, refuse-after=S "
@@ -366,7 +370,7 @@ def main() -> None:
         # from the fleet-shared directory instead of re-compiling
         from incubator_predictionio_tpu.utils import compile_cache
 
-        compile_cache.enable(args.compile_cache)
+        compile_cache.enable()
 
     from incubator_predictionio_tpu.obs import metrics as obs_metrics
     from incubator_predictionio_tpu.obs import trace as obs_trace

@@ -529,5 +529,10 @@ def test_bench_emits_one_parsed_record_end_to_end(tmp_path):
         assert rec["shard_train_wall_s"] > 0
         assert rec["shard_allgather_bytes"] > 0
         assert rec["shard_mfu_train"] > 0
+        # VMEM arithmetic for the fused-gather kernel under the gather
+        # modes auto resolves at ML-20M shape: the all-gathered item
+        # table (6.9 MB bf16) fits its budget, the all-gathered user
+        # table (35 MB) does not — auto no longer picks the ring to make
+        # a slice fit a kernel that does not lower on the TPU compiler
         assert rec["shard_fused_fits_ml20m_user_sweep"] is True
-        assert rec["shard_fused_fits_ml20m_item_sweep"] is True
+        assert rec["shard_fused_fits_ml20m_item_sweep"] is False

@@ -1,14 +1,14 @@
-"""Capacity/regression model over the checked-in bench trajectory.
+"""Capacity/regression model over a bench trajectory.
 
-The acceptance contract: ``scripts/capacity_report.py`` run over the
-repo's real ``BENCH_*.json``/``MULTICHIP_*.json`` emits a
+The acceptance contract: ``scripts/capacity_report.py`` run over a
+directory of ``BENCH_*.json``/``MULTICHIP_*.json`` records emits a
 ``capacity.json`` with a rows-per-chip estimate and a NON-NULL verdict
 for every record — including structured reasons for the r04/r05-style
 failed runs (``accelerator init still blocked`` rc=3, driver-kill
-rc=124), which used to be unexplainable ``parsed: null`` rows. The
-unit tests pin the failure classifier, the tolerance compare and the
-record normalizer on synthetic records so the contract outlives the
-particular files checked in today.
+rc=124), which used to be unexplainable ``parsed: null`` rows. Every
+test writes its own records under ``tmp_path`` (the driver's old
+``BENCH_r01``–``r05`` files are gone; ``PERF_LEDGER.jsonl`` is the
+record now), so the contract does not depend on what is checked in.
 """
 
 import json
@@ -24,12 +24,47 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "scripts", "capacity_report.py")
 
 
-# -- the tier-1 gate: the real script over the real trajectory --------------
+# -- the tier-1 gate: the real script over an r04/r05-shaped trajectory ------
 
-def test_capacity_report_check_over_checked_in_records(tmp_path):
+def _write_failed_run_trajectory(root):
+    """The record shapes the driver really produced, written fresh: one
+    fully parsed chip-backed builder record (the pinned baseline's
+    source), an rc=3 run whose tail says the accelerator never
+    initialized, an rc=124 run the driver killed at its deadline, and a
+    dry-run multichip record — plus a copy of the pinned baseline."""
+    import shutil
+
+    shutil.copy(os.path.join(REPO, "CAPACITY_BASELINE.json"),
+                root / "CAPACITY_BASELINE.json")
+    base = capacity.load_baseline(REPO)
+    (root / "BENCH_r04_builder.json").write_text(json.dumps(base["keys"]))
+    blocked = "".join(
+        f"accelerator init still blocked (attempt {i}) — retrying\n"
+        for i in range(1, 11))
+    (root / "BENCH_r04.json").write_text(json.dumps({
+        "n": 4, "cmd": "python bench.py", "rc": 3, "parsed": None,
+        "tail": blocked + "accelerator unavailable after 1200s; "
+                          "aborting\n"}))
+    (root / "BENCH_r05.json").write_text(json.dumps({
+        "n": 5, "cmd": "python bench.py", "rc": 124, "parsed": None,
+        "tail": "dataset: 138493x26744, nnz=20000000, rank=128\n"
+                "seed: 20000000 events in 32.9s (0.61M ev/s)\n"
+                "ingest scan: 13.1s (1.52M ev/s)\n"
+                "prep (bucketed padded rows): 7.1s\n"
+                "tpu child attempt 1 did not claim within 180s — "
+                "recycling\n"
+                "tpu child attempt 3 did not claim within 720s — "
+                "recycling\n"}))
+    (root / "MULTICHIP_r05.json").write_text(json.dumps({
+        "n_devices": 8, "rc": 0, "ok": True, "skipped": False,
+        "tail": ""}))
+
+
+def test_capacity_report_check_over_failed_run_records(tmp_path):
+    _write_failed_run_trajectory(tmp_path)
     out = tmp_path / "capacity.json"
     proc = subprocess.run(
-        [sys.executable, SCRIPT, "--repo-dir", REPO,
+        [sys.executable, SCRIPT, "--repo-dir", str(tmp_path),
          "--out", str(out), "--check"],
         capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr[-2000:]
@@ -50,6 +85,8 @@ def test_capacity_report_check_over_checked_in_records(tmp_path):
         assert rec["verdict"] is not None, rec["name"]
         assert rec["verdict"].get("status"), rec["name"]
         by_name[rec["name"]] = rec
+    assert set(by_name) == {"BENCH_r04_builder", "BENCH_r04", "BENCH_r05",
+                            "MULTICHIP_r05"}
 
     # the r04/r05 failure modes are STRUCTURED, never bare nulls
     r04 = by_name["BENCH_r04"]

@@ -264,16 +264,16 @@ def test_import_fast_path_uniform_batch(tmp_path, capsys, monkeypatch):
 
 
 def test_accelerator_watchdog_times_out_and_propagates_errors(monkeypatch):
-    """A chip claimed by another process blocks device init forever; the
-    probe must turn that into an actionable error, and real init errors
-    must surface as themselves."""
+    """A device init that hangs must turn into an actionable error, and
+    real init errors (on a local chip: libtpu's lock held by another
+    process) must surface as themselves."""
     import time
 
     from incubator_predictionio_tpu.cli import main as climain
     import jax
 
     monkeypatch.setattr(jax, "devices", lambda: time.sleep(30))
-    with pytest.raises(climain.CommandError, match="holds the chip"):
+    with pytest.raises(climain.CommandError, match="another process holds it"):
         climain._ensure_accelerator(0.2)
 
     def boom():

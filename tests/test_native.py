@@ -502,3 +502,30 @@ class TestUniformBatchFastPath:
         ids2 = d.insert_batch(explicit, 1)
         assert ids2 == [f"{k:032d}" for k in range(10)]
         c.close()
+
+
+def test_build_is_keyed_by_source_content_not_mtime(tmp_path, monkeypatch):
+    """A copied tree's mtimes say nothing: the library's name carries a
+    digest of the sources, so changed CONTENT under an unchanged (even
+    older) mtime builds and loads a new library, and the stale one is
+    removed."""
+    import os
+    import shutil
+
+    src = tmp_path / "src"
+    shutil.copytree(native._SRC_DIR, src)
+    monkeypatch.setattr(native, "_SRC_DIR", src)
+    monkeypatch.setattr(native, "_BUILD_DIR", tmp_path / "_build")
+    first = native.build()
+    assert first.exists() and native.build() == first  # idempotent
+    target = src / "csr_builder.cc"
+    before = target.stat()
+    target.write_text(target.read_text() + "\n// content changed\n")
+    # the .so is NEWER than every source: the old mtime rule would keep it
+    os.utime(target, ns=(before.st_atime_ns, before.st_mtime_ns))
+    assert target.stat().st_mtime_ns < first.stat().st_mtime_ns
+    second = native.build()
+    assert second != first and second.exists() and not first.exists()
+    import ctypes
+
+    assert ctypes.CDLL(str(second)).pio_csr_plan is not None

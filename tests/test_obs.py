@@ -318,8 +318,15 @@ def test_metrics_route_on_all_four_servers(stack):
 def test_compile_cache_metrics_registered(tmp_path, monkeypatch):
     from incubator_predictionio_tpu.utils import compile_cache
 
+    import jax
+
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    compile_cache.enable(str(tmp_path))
+    monkeypatch.setattr(compile_cache, "_enabled", False)
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        compile_cache.enable()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
     text = obs_metrics.REGISTRY.expose()
     types, samples = parse_exposition(text)
     assert types["pio_compile_cache_hits_total"] == "counter"

@@ -554,8 +554,9 @@ def test_warmup_recommendation_batched_and_sequence():
 
     algo.batch_predict = spy
     algo.warmup(models[0], max_batch=5)
-    # size=2 start, cap = next_pow2(5) = 8 → exactly [2, 4, 8]
-    assert calls == [2, 4, 8]
+    # the whole ladder, rung 1 included (a lone plain query rides the
+    # batched fast path at B=1); cap = next_pow2(5) = 8 → [1, 2, 4, 8]
+    assert calls == [1, 2, 4, 8]
     algo.batch_predict = orig
     algo.warmup(models[0], max_batch=0)   # disabled batcher: singleton only
 

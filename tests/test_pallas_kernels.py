@@ -331,37 +331,65 @@ def test_flash_block_table_selection(monkeypatch):
     assert pk._parse_block_env() is None
 
 
-def test_als_probe_compiles_the_variant_the_caller_runs(monkeypatch):
-    """als_kernel_available(warm=...) must probe the EXACT kernel variant
-    the caller will dispatch (warm adds the x0 operand — a different
-    Mosaic kernel) and cache per variant, so a cold-only probe can never
-    green-light a warm run or vice versa (the ADVICE.md round-5 probe
-    gap)."""
+def test_availability_is_the_backend_test_and_never_a_probe(monkeypatch):
+    """Every *_available() routing predicate is `backend == tpu` and
+    nothing more: no kernel is compiled or run to decide a route, so no
+    compile or run error can be converted into False (and into a quiet
+    reroute to an XLA path)."""
     from incubator_predictionio_tpu.ops import pallas_kernels as pk
 
-    probed = []
+    predicates = (pk.pallas_available, pk.topk_kernel_available,
+                  pk.flash_available, pk.als_kernel_available)
+    assert not any(p() for p in predicates)     # the CPU test backend
 
-    def fake_probe(fn, what):
-        probed.append(what)
-        return True
+    def boom(*a, **kw):
+        raise AssertionError("a routing predicate ran a kernel")
 
-    monkeypatch.setattr(pk, "pallas_available", lambda: True)
-    monkeypatch.setattr(pk, "_probe_kernel_runs", fake_probe)
-    monkeypatch.setattr(pk, "_als_ok", {})
+    for entry in ("score_and_top_k_pallas", "flash_attention",
+                  "als_solve_cg_pallas", "als_fused_solve_cg_pallas"):
+        monkeypatch.setattr(pk, entry, boom)
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+    assert all(p() for p in predicates)
+    assert not hasattr(pk, "_probe_kernel_runs")
+    assert not hasattr(pk, "_probe_mosaic")
 
-    assert pk.als_kernel_available(warm=True)
-    assert pk.als_kernel_available(warm=False)
-    assert pk.als_kernel_available(warm=True)   # cached, no new probe
-    assert probed == ["ALS bucket CG solve (warm)",
-                      "ALS bucket CG solve (cold)"]
-    # the fused-gather generation is a DIFFERENT kernel family again
-    # (in-kernel jnp.take gather; implicit adds the yty operand) — each
-    # (warm, fused, implicit) variant probes and caches separately, so
-    # production can never run a fused/implicit kernel the probe only
-    # green-lit in its two-stage/explicit form
-    assert pk.als_kernel_available(warm=True, fused=True)
-    assert pk.als_kernel_available(warm=False, fused=True, implicit=True)
-    assert pk.als_kernel_available(warm=True, fused=True)  # cached
-    assert probed[2:] == [
-        "ALS fused gather+Gram CG solve (warm)",
-        "ALS fused gather+Gram CG solve (cold, implicit)"]
+
+def test_interpret_never_resolves_true_on_a_tpu_backend(monkeypatch):
+    """interpret=None → interpret mode exactly when the backend is not a
+    TPU; on a TPU backend no entry can resolve interpret=True."""
+    from incubator_predictionio_tpu.ops import pallas_kernels as pk
+
+    assert pk._resolve_interpret(None) is True      # CPU backend
+    assert pk._resolve_interpret(False) is False
+    assert pk._resolve_interpret(True) is True
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+    assert pk._resolve_interpret(None) is False
+    assert pk._resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret=True on a TPU"):
+        pk._resolve_interpret(True)
+
+
+def test_auto_route_on_tpu_selects_two_stage_never_the_fused_kernel(
+        monkeypatch):
+    """ops/als.py routing on a TPU backend: `auto` selects the two-stage
+    kernel (which compiles — tests/test_tpu_aot_compile.py) and never the
+    fused-gather kernel (which does not lower); only the explicit
+    PIO_ALS_FUSED_GRAM=on test hook turns that one on."""
+    from incubator_predictionio_tpu.ops import als
+    from incubator_predictionio_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(als, "_ALS_KERNEL", "auto")
+    monkeypatch.delenv("PIO_ALS_FUSED_GRAM", raising=False)
+    assert als._kernel_enabled(False, warm=True) is False    # CPU
+    monkeypatch.setattr(pk.jax, "default_backend", lambda: "tpu")
+    for warm in (False, True):
+        assert als._kernel_enabled(False, warm=warm) is True
+        assert als._fused_enabled(False, warm) is False
+        assert als._fused_enabled(True, warm) is False
+        assert als._kernel_enabled(True, warm=warm) is False  # implicit
+    assert als._fused_sides(138_493, 26_744, False, True,
+                            jnp.bfloat16, 128) == (False, False)
+    monkeypatch.setenv("PIO_ALS_FUSED_GRAM", "on")
+    assert als._fused_enabled(False, True) is True
+    assert als._fused_sides(138_493, 26_744, False, True,
+                            jnp.bfloat16, 128) == (True, False)

@@ -228,3 +228,25 @@ def test_num_zero_returns_empty_on_both_paths(seeded_app):
     assert algo.predict(models[0], Query(user="uA1", num=0)).item_scores == ()
     object.__setattr__(models[0], "_np_cache", False)
     assert algo.predict(models[0], Query(user="uA1", num=0)).item_scores == ()
+
+
+def test_warmup_covers_the_lone_query_fast_path(seeded_app):
+    """A lone plain query rides the columnar fast path (batch_serve_json →
+    batch_score_top_k at B=1), not predict(): deploy-time warmup must
+    have compiled that rung too, or the first live query compiles on the
+    dispatcher thread (0.2–0.3 s on a v5e — enough to push the latency
+    p99 over the serve SLO and make the scheduler shed the next burst)."""
+    from incubator_predictionio_tpu.ops import topk
+
+    engine = RecommendationEngine().apply()
+    models = engine.train(RuntimeContext(), engine_params())
+    algo = engine.algorithms(engine_params())[0]
+    object.__setattr__(models[0], "_np_cache", False)  # device path
+    algo.warmup(models[0], max_batch=8)
+    warm = topk.serve_compile_cache_size()
+    for width in (1, 2, 3, 8):
+        docs = [{"user": "uA1", "num": 10}] * width   # the warmed k
+        assert all(algo.batch_serve_json(models[0], docs))
+    assert algo.predict(models[0], Query(user="uA1", num=10)).item_scores
+    assert topk.serve_compile_cache_size() == warm, \
+        "a live width compiled after warmup"

@@ -525,9 +525,18 @@ def test_profiler_off_by_default(monkeypatch):
     obs_profile.record(None, "train", "x", 1e9, object())
 
 
+def _this_device_kind():
+    import jax
+
+    return jax.devices()[0].device_kind
+
+
 def test_profiler_attribution_and_mfu(monkeypatch):
     monkeypatch.setenv("PIO_PROFILE", "1")
-    monkeypatch.setenv("PIO_BENCH_PEAK_FLOPS", "1e12")
+    # the peak comes from the device_kind table and nowhere else: list
+    # this (CPU) device for the test; the old PIO_BENCH_* variable is dead
+    monkeypatch.setitem(obs_profile.PEAK_FLOPS, _this_device_kind(), 1e12)
+    monkeypatch.setenv("PIO_BENCH_PEAK_FLOPS", "5e12")
     t0 = obs_profile.t0()
     assert t0 is not None
     obs_profile.record(t0, "t_phase", "t_op", 2e9)
@@ -539,10 +548,34 @@ def test_profiler_attribution_and_mfu(monkeypatch):
     assert mfu == pytest.approx(2e9 / secs / 1e12, rel=1e-6)
 
 
+def test_unknown_device_leaves_mfu_unset_and_logs_once(monkeypatch, caplog):
+    """A device_kind the table does not list has no peak: device time
+    and FLOPs still book, pio_mfu is never set (no default), and the
+    warning is logged once."""
+    import logging
+
+    monkeypatch.setenv("PIO_PROFILE", "1")
+    monkeypatch.delitem(obs_profile.PEAK_FLOPS, _this_device_kind(),
+                        raising=False)
+    monkeypatch.setattr(obs_profile, "_no_peak_logged", set())
+    assert obs_profile.PEAK_FLOPS["TPU v5 lite"] == 197e12
+    with caplog.at_level(logging.WARNING, logger=obs_profile.__name__):
+        for _ in range(2):
+            obs_profile.record(obs_profile.t0(), "u_phase", "u_op", 2e9)
+    assert obs_profile.peak_flops() is None
+    assert obs_profile.DEVICE_FLOPS.labels(op="u_op").value == 4e9
+    out: list = []
+    obs_profile.MFU.expose_into(out)
+    assert not any("u_phase" in line for line in out)
+    assert sum("no published peak" in r.message
+               for r in caplog.records) == 1
+
+
 def test_profiled_foldin_books_device_time(monkeypatch):
     from incubator_predictionio_tpu.speed.foldin import FoldInSolver
 
     monkeypatch.setenv("PIO_PROFILE", "1")
+    monkeypatch.setitem(obs_profile.PEAK_FLOPS, _this_device_kind(), 1e12)
     rng = np.random.default_rng(0)
     other = rng.normal(0, 0.3, (20, 4)).astype(np.float32)
     solver = FoldInSolver(other, l2=0.1)

@@ -752,14 +752,15 @@ class ServeBlockingIO(Rule):
 #: profiler-capture entry points — each one either serializes the device
 #: (block_until_ready per query) or starts a process-wide trace capture;
 #: both are catastrophic inside a predict path that is supposed to
-#: pipeline dispatches
+#: pipeline dispatches. jax.profiler.TraceAnnotation is neither (with no
+#: capture running it is one atomic read); serve-path code reaches it
+#: through obs/trace.stage
 _PROFILER_CAPTURE_CALLS = {
     "jax.block_until_ready",
     "jax.profiler.start_trace",
     "jax.profiler.stop_trace",
     "jax.profiler.trace",
     "jax.profiler.start_server",
-    "jax.profiler.TraceAnnotation",
 }
 
 

@@ -43,6 +43,7 @@ from incubator_predictionio_tpu.core.self_cleaning import (
 from incubator_predictionio_tpu.data.bimap import BiMap
 from incubator_predictionio_tpu.data.storage.base import Interactions
 from incubator_predictionio_tpu.data.store import EventStore
+from incubator_predictionio_tpu.obs.trace import stage
 from incubator_predictionio_tpu.parallel.context import RuntimeContext
 
 logger = logging.getLogger(__name__)
@@ -768,10 +769,18 @@ class ALSAlgorithm(Algorithm):
             # matmul is a few ms at any batch size, always under the
             # device dispatch+fetch round trip such a model would pay
             np_users, np_items = host
-            top_s, top_i = host_batch_top_k(np_users[rows] @ np_items.T, k)
+            with stage("serve.host_score"):
+                top_s, top_i = host_batch_top_k(
+                    np_users[rows] @ np_items.T, k)
             return [(top_s[b], top_i[b]) for b in range(len(rows))]
-        packed = np.asarray(batch_score_top_k(     # ONE fetch
-            model.user_factors, model.item_factors, rows, k))
+        # launch returns once the program is enqueued; the fetch then
+        # waits for the device and copies down, so the device's busy time
+        # lies inside serve.fetch
+        with stage("serve.launch"):
+            on_device = batch_score_top_k(
+                model.user_factors, model.item_factors, rows, k)
+        with stage("serve.fetch"):
+            packed = np.asarray(on_device)     # ONE fetch
         return [(packed[0][b], packed[1][b].astype(np.int64))
                 for b in range(len(rows))]
 
@@ -828,18 +837,20 @@ class ALSAlgorithm(Algorithm):
         get_row = model.user_bimap.get
         ov = self.speed_overlay
         plain = []  # (slot, row, num)
-        for slot, d in enumerate(docs):
-            if (type(d) is dict and len(d) == 2 and "user" in d
-                    and "num" in d):
-                u, num = d["user"], d["num"]
-                if (isinstance(u, str) and isinstance(num, int)
-                        and not isinstance(num, bool) and num > 0):
-                    row = get_row(u)
-                    # overlay-covered users fall to the object path: the
-                    # rendered bytes must reflect the folded-in vector
-                    if row is not None and (ov is None
-                                            or not ov.covers(u)):
-                        plain.append((slot, row, num))
+        with stage("serve.lookup"):
+            for slot, d in enumerate(docs):
+                if (type(d) is dict and len(d) == 2 and "user" in d
+                        and "num" in d):
+                    u, num = d["user"], d["num"]
+                    if (isinstance(u, str) and isinstance(num, int)
+                            and not isinstance(num, bool) and num > 0):
+                        row = get_row(u)
+                        # overlay-covered users fall to the object path:
+                        # the rendered bytes must reflect the folded-in
+                        # vector
+                        if row is not None and (ov is None
+                                                or not ov.covers(u)):
+                            plain.append((slot, row, num))
         out: list = [None] * len(docs)
         if not plain:
             return out
@@ -850,28 +861,30 @@ class ALSAlgorithm(Algorithm):
         years = model.item_years
         dumps = _json.dumps
         isfinite = math.isfinite
-        for (slot, _row, num), (top_s, top_i) in zip(plain, tops):
-            parts = []
-            ok = True
-            for s, i in zip(top_s[:num].tolist(), top_i[:num].tolist()):
-                if s > -1e37:
-                    if not isfinite(s):
-                        # repr(inf) is not JSON (json.dumps says
-                        # 'Infinity') — an overflowed score falls back
-                        # to the object path rather than diverge
-                        ok = False
-                        break
-                    iid = inv[i]
-                    y = years.get(iid)
-                    # mirror json.dumps' default formatting exactly
-                    # (', '/': ' separators, float repr)
-                    parts.append('{"item": %s, "score": %s, '
-                                 '"creationYear": %s}'
-                                 % (dumps(iid), repr(s),
-                                    "null" if y is None else repr(y)))
-            if ok:
-                out[slot] = ('{"itemScores": [' + ", ".join(parts)
-                             + "]}").encode("utf-8")
+        with stage("serve.render"):
+            for (slot, _row, num), (top_s, top_i) in zip(plain, tops):
+                parts = []
+                ok = True
+                for s, i in zip(top_s[:num].tolist(),
+                                top_i[:num].tolist()):
+                    if s > -1e37:
+                        if not isfinite(s):
+                            # repr(inf) is not JSON (json.dumps says
+                            # 'Infinity') — an overflowed score falls
+                            # back to the object path rather than diverge
+                            ok = False
+                            break
+                        iid = inv[i]
+                        y = years.get(iid)
+                        # mirror json.dumps' default formatting exactly
+                        # (', '/': ' separators, float repr)
+                        parts.append('{"item": %s, "score": %s, '
+                                     '"creationYear": %s}'
+                                     % (dumps(iid), repr(s),
+                                        "null" if y is None else repr(y)))
+                if ok:
+                    out[slot] = ('{"itemScores": [' + ", ".join(parts)
+                                 + "]}").encode("utf-8")
         return out
 
 

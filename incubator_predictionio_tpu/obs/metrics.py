@@ -50,6 +50,22 @@ DEFAULT_LATENCY_BUCKETS: Tuple[float, ...] = tuple(
 )
 
 
+#: the widest ratio between two bounds of :func:`geometric_buckets`
+_MAX_BUCKET_STEP = 1.5
+
+
+def geometric_buckets(lo: float, hi: float) -> Tuple[float, ...]:
+    """Bounds from ``lo`` to ``hi`` in equal ratio steps of at most 1.5,
+    rounded to three significant digits (so the ``le`` labels read
+    cleanly). For series whose quantiles a reader derives from the
+    buckets: a doubling ladder reads a p95 to within a factor of two,
+    this one to within 1.5."""
+    n = max(int(math.ceil(math.log(hi / lo) / math.log(_MAX_BUCKET_STEP))),
+            1)
+    ratio = (hi / lo) ** (1.0 / n)
+    return tuple(float(f"{lo * ratio ** i:.3g}") for i in range(n + 1))
+
+
 def _fmt(v: float) -> str:
     """Prometheus sample value: ints render bare, floats via repr."""
     if isinstance(v, bool):

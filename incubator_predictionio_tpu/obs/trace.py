@@ -16,19 +16,32 @@ A client that stamps its POST /events.json and POST /queries.json with
 the same trace ID can therefore join the ingest span, the serving span
 and any operator-side logs on one key — the distributed-tracing
 contract at log-line cost, with no collector dependency.
+
+Below the request level, :func:`stage` marks the boundaries of the
+serving dispatcher's cycle (``serve.wait``, ``serve.dispatch`` and its
+children; docs/observability.md "The dispatcher's cycle"): one call
+feeds the profiler's host timeline, while a ``jax.profiler`` trace is
+being taken, and ``pio_serve_phase_seconds_total{phase}`` always.
+:class:`GcPauseHook` does the same for the collector's pauses.
 """
 
 from __future__ import annotations
 
+import collections
 import contextvars
+import gc
 import json
 import logging
 import os
 import random
 import re
 import secrets
+import sys
+import threading
 import time
-from typing import Any, Optional, Tuple
+from typing import Any, Deque, Dict, Optional, Tuple
+
+from incubator_predictionio_tpu.obs import metrics as obs_metrics
 
 #: the propagation header, request and response side
 TRACE_HEADER = "X-PIO-Trace-Id"
@@ -234,3 +247,200 @@ def log_stage_span(span: str, trace_id: str, duration_s: float,
     if extra:
         record.update(extra)
     span_logger.info("%s", json.dumps(record, separators=(",", ":")))
+
+
+# ---------------------------------------------------------------------------
+# stages of the serving dispatcher's cycle: one primitive, two sinks
+# ---------------------------------------------------------------------------
+
+#: seconds the dispatcher threads spent in each phase of their cycle.
+#: ``wait`` and the phases inside a dispatch tile a dispatcher thread's
+#: life: summed over ``phase`` the family grows by one second per second
+#: per dispatcher thread (tests/test_serve_phases.py holds it to that).
+_PHASE_SECONDS = obs_metrics.REGISTRY.counter(
+    "pio_serve_phase_seconds_total",
+    "seconds of the serving dispatcher's cycle, by phase (wait = nothing "
+    "it may pick; other = a dispatch's own time outside its child "
+    "phases; fetch = device execution + device-to-host copy)",
+    labels=("phase",))
+_phase_children: Dict[str, Any] = {}
+_annotation_cls: Any = None
+
+
+class _StageLocal(threading.local):
+    #: the innermost open stage on this thread (class default: a thread
+    #: that never opened one reads None without a miss)
+    top: Any = None
+
+
+_stage_local = _StageLocal()
+
+
+def _annotation() -> Any:
+    """``jax.profiler.TraceAnnotation``, or None in a process that never
+    imported jax (the event and storage servers share this module and
+    must not pay for, or initialize, jax). It neither synchronizes the
+    device nor starts a capture; whether one is running is one atomic
+    read (``is_enabled``), and only then is an annotation made."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls
+
+
+def _phase_child(name: str, phase: Optional[str]) -> Any:
+    """The counter child a stage books under, cached by the stage's
+    name: ``phase`` if given, else the name's last component."""
+    child = _phase_children[name] = _PHASE_SECONDS.labels(
+        phase=phase or name.rpartition(".")[2])
+    return child
+
+
+class stage:
+    """``with stage("serve.fetch"):`` — one boundary of the dispatcher's
+    cycle. While a ``jax.profiler`` trace is being taken it holds an
+    annotation of that name (with ``attrs``) open on the calling
+    thread's line of the host plane, on the device trace's own clock;
+    always, on exit, it adds its SELF time — its duration less the
+    stages nested inside it on the same thread — to
+    ``pio_serve_phase_seconds_total{phase}``, so no second is counted
+    twice. ``phase`` defaults to the name's last component. The clock
+    is read first on entry and last on exit: a stage's own bookkeeping
+    is inside its time, so consecutive stages tile a thread's time to
+    within a microsecond. Called per dispatch, never per query."""
+
+    __slots__ = ("_name", "_phase", "_attrs", "_ann", "_parent", "_t0",
+                 "_child_s")
+
+    def __init__(self, name: str, phase: Optional[str] = None,
+                 **attrs: Any) -> None:
+        self._name = name
+        self._phase = phase
+        self._attrs = attrs
+
+    def __enter__(self) -> "stage":
+        self._t0 = time.perf_counter()
+        self._child_s = 0.0
+        local = _stage_local
+        parent = self._parent = local.top
+        local.top = self
+        self._ann = None
+        if parent is not None:
+            # a trace that starts in mid-dispatch shows from the next one
+            traced = parent._ann is not None
+        else:
+            cls = _annotation_cls or _annotation()
+            traced = cls is not None and cls.is_enabled()
+        if traced:
+            self._ann = _annotation_cls(self._name, **self._attrs)
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        parent = _stage_local.top = self._parent
+        child = _phase_children.get(self._name) or _phase_child(
+            self._name, self._phase)
+        dt = time.perf_counter() - self._t0
+        if parent is not None:
+            parent._child_s += dt
+        dt -= self._child_s
+        child.inc(dt if dt > 0.0 else 0.0)
+
+
+#: one collection of the serving process, start to stop, by generation:
+#: the stall the harness's own callback found (PERF.md, PR 25) is a full
+#: collection of 38-48 ms; an operator reads it here
+_GC_PAUSE = obs_metrics.REGISTRY.histogram(
+    "pio_gc_pause_seconds",
+    "one garbage collection of the serving process, start to stop",
+    labels=("generation",),
+    buckets=obs_metrics.geometric_buckets(0.25e-3, 1.0))
+
+
+class GcPauseHook:
+    """A ``gc.callbacks`` hook: holds a ``gc.pause`` annotation open
+    from a collection's start to its stop and books the pause in
+    ``pio_gc_pause_seconds{generation}``. It books nothing under
+    ``pio_serve_phase_seconds_total``: a collection runs inside whatever
+    phase set it off, and the phases go on tiling the thread's time.
+
+    The hook itself takes no lock: a collection can start between two
+    bytecodes of ANY thread, the scrape thread inside the histogram's
+    own lock included, and a locked add from there would wait on
+    itself. It appends to a bounded deque (two clock reads and one
+    append a collection) and a scrape-time collector moves the pauses
+    into the histogram."""
+
+    #: pauses kept between two scrapes; beyond it the oldest are dropped
+    PENDING_MAX = 16384
+
+    def __init__(self) -> None:
+        self._pending: Deque[Tuple[int, float]] = collections.deque(
+            maxlen=self.PENDING_MAX)
+        self._ann: Any = None
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            cls = _annotation()
+            if cls is not None and cls.is_enabled():
+                self._ann = cls("gc.pause", gen=info.get("generation", -1))
+                self._ann.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0:  # a stop whose start came before the hook: skip
+            dt = time.perf_counter() - self._t0
+            self._t0 = 0.0
+            ann, self._ann = self._ann, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+            self._pending.append((info.get("generation", -1), dt))
+
+    def flush(self) -> None:
+        """Scrape time: the pauses since the last flush → the histogram.
+        The ambient trace here is the scrape's own request, which no
+        pause belongs to: cleared, so that none becomes an exemplar."""
+        token = set_current(None)
+        try:
+            while True:
+                try:
+                    gen, dt = self._pending.popleft()
+                except IndexError:
+                    return
+                _GC_PAUSE.labels(generation=str(gen)).observe(dt)
+        finally:
+            reset_current(token)
+
+
+#: the collector is the process's, so the hook is too: servers that
+#: start serving hold it (the ops/mips_daemon acquire/release idiom) and
+#: the last one to stop takes it out of ``gc.callbacks``
+_gc_hook = GcPauseHook()
+_gc_lock = threading.Lock()
+_gc_holders = 0
+
+
+def acquire_gc_hook() -> None:
+    global _gc_holders
+    with _gc_lock:
+        _gc_holders += 1
+        if _gc_holders == 1:
+            gc.callbacks.append(_gc_hook)
+            obs_metrics.REGISTRY.register_collector("gc_pause",
+                                                    _gc_hook.flush)
+
+
+def release_gc_hook() -> None:
+    global _gc_holders
+    with _gc_lock:
+        if _gc_holders == 0:
+            return
+        _gc_holders -= 1
+        if _gc_holders == 0:
+            gc.callbacks.remove(_gc_hook)
+            obs_metrics.REGISTRY.unregister_collector("gc_pause")
+            _gc_hook.flush()

@@ -95,6 +95,52 @@ _STALENESS = obs_metrics.REGISTRY.gauge(
     "pio_model_staleness_seconds",
     "seconds since the served engine instance finished training "
     "(scrape-time snapshot)")
+#: an answer the dispatcher has finished waits for the event loop to pick
+#: it up (asyncio.wrap_future): one query of each dispatch is sampled, on
+#: the loop's thread (serving/scheduler.py stamps it). Steps of at most
+#: 1.5, not doubling: its p95 is read from the buckets
+_REPLY_LAG = obs_metrics.REGISTRY.histogram(
+    "pio_serve_reply_lag_seconds",
+    "future resolved on the dispatcher's thread to handler resumed on "
+    "the event loop's, one query a dispatch",
+    buckets=obs_metrics.geometric_buckets(50e-6, 1.0))
+#: what GET /ready answers from, the load-balancer probe, read off the
+#: newest server at scrape time: 0 until the deploy's warm-up thread has
+#: ended, 1 from then on — a /reload warms its new models BEFORE the swap
+#: while the old ones serve, so it stays 1
+_READY = obs_metrics.REGISTRY.gauge(
+    "pio_serve_ready",
+    "1 once the serving warm-up has ended (GET /ready answers 200), "
+    "0 before")
+_DEVICE_BYTES = obs_metrics.REGISTRY.gauge(
+    "pio_device_bytes_in_use",
+    "device memory in use (memory_stats() at scrape time; absent on a "
+    "backend that reports none)", labels=("device",))
+_DEVICE_PEAK_BYTES = obs_metrics.REGISTRY.gauge(
+    "pio_device_peak_bytes_in_use",
+    "peak device memory in use since the process started "
+    "(memory_stats() at scrape time)", labels=("device",))
+
+
+def _collect_device_memory() -> None:
+    # scrape time only, never on the serving path. Registered by a
+    # PredictionServer, whose models already live on the device: a
+    # process that merely shares the registry never initializes a
+    # backend for the sake of a gauge
+    import jax
+
+    # the label is the device's place among this process's devices: as
+    # many values as the host has chips
+    for n, d in enumerate(jax.local_devices()):
+        stats = d.memory_stats()
+        if not stats:
+            continue
+        if "bytes_in_use" in stats:
+            _DEVICE_BYTES.labels(device=str(n)).set(
+                float(stats["bytes_in_use"]))
+        if "peak_bytes_in_use" in stats:
+            _DEVICE_PEAK_BYTES.labels(device=str(n)).set(
+                float(stats["peak_bytes_in_use"]))
 
 
 @dataclasses.dataclass
@@ -337,6 +383,18 @@ class PredictionServer:
 
         obs_metrics.REGISTRY.register_collector(
             "prediction_model_staleness", _collect_staleness)
+        obs_metrics.REGISTRY.register_collector(
+            "device_memory", _collect_device_memory)
+        self._ready = False
+
+        def _collect_ready() -> None:
+            s = server_ref()
+            if s is not None:
+                _READY.set(1 if s._ready else 0)
+
+        obs_metrics.REGISTRY.register_collector(
+            "serve_ready", _collect_ready)
+        self._gc_hook_held = False
         # feedback events are training data: a deep queue so only a
         # sustained collector outage drops (drops counted and shown on the
         # status page); --log-url diagnostics stay shallow and lossy
@@ -615,28 +673,32 @@ class PredictionServer:
         query_class = algorithms[0].query_class
         results: List[Any] = [None] * n
         raws: List[Any] = [None] * n
-        for idx, body in enumerate(bodies):
-            try:
-                raws[idx] = json.loads(body.decode("utf-8"))
-            except Exception as e:
-                results[idx] = e
-        # columnar serving fast path (core/base.py batch_serve_json): only
-        # when the rendered bytes are observably identical to the object
-        # path — one algorithm, declared first-prediction serving with the
-        # inherited identity supplement, and nothing downstream that needs
-        # the result as an object (feedback loop, output plugins)
         from incubator_predictionio_tpu.core.base import Serving
 
-        # the flag must be declared on the serving's OWN class: a subclass
-        # that overrides serve() would silently inherit True and its
-        # serve() would never run on fast-path responses
-        if (len(algorithms) == 1
+        with obs_trace.stage("serve.parse"):
+            for idx, body in enumerate(bodies):
+                try:
+                    raws[idx] = json.loads(body.decode("utf-8"))
+                except Exception as e:
+                    results[idx] = e
+            # columnar serving fast path (core/base.py batch_serve_json):
+            # only when the rendered bytes are observably identical to the
+            # object path — one algorithm, declared first-prediction
+            # serving with the inherited identity supplement, and nothing
+            # downstream that needs the result as an object (feedback
+            # loop, output plugins). The flag must be declared on the
+            # serving's OWN class: a subclass that overrides serve() would
+            # silently inherit True and its serve() would never run on
+            # fast-path responses
+            fast_path = (
+                len(algorithms) == 1
                 and type(serving).__dict__.get("FIRST_PREDICTION_ONLY",
                                                False)
                 and type(serving).supplement is Serving.supplement
                 and not self.config.feedback
                 and not self.plugin_context.output_blockers
-                and not self.plugin_context.output_sniffers):
+                and not self.plugin_context.output_sniffers)
+        if fast_path:
             try:
                 fast = algorithms[0].batch_serve_json(
                     models[0],
@@ -985,11 +1047,23 @@ class PredictionServer:
                             "x-pio-priority", "0"))
                     except ValueError:
                         prio = 0
-                    result = await asyncio.wrap_future(
-                        self._batcher.submit(
-                            request.body, priority=prio,
-                            engine=self.config.engine_id,
-                            tenant=tenant))
+                    fut = self._batcher.submit(
+                        request.body, priority=prio,
+                        engine=self.config.engine_id, tenant=tenant)
+                    result = await asyncio.wrap_future(fut)
+                    t_resolved = getattr(fut, "resolved_at", None)
+                    if t_resolved is not None:
+                        # this dispatch's sample: resolved on the
+                        # dispatcher's thread → resumed here, on the
+                        # loop's; inside the client's latency and in no
+                        # other series. It stands for the dispatch, so no
+                        # one request's trace is its exemplar
+                        lag = max(time.perf_counter() - t_resolved, 0.0)
+                        token = obs_trace.set_current(None)
+                        try:
+                            _REPLY_LAG.observe(lag)
+                        finally:
+                            obs_trace.reset_current(token)
                 else:
                     result = await sync(self._handle_query, request.body,
                                         tenant)
@@ -1015,6 +1089,14 @@ class PredictionServer:
                 return Response(200, body=bytes(result),
                                 headers=depth_headers)
             return Response(200, result, headers=depth_headers)
+
+        @r.get("/ready")
+        def ready(request: Request) -> Response:
+            # getattr: harnesses build servers via __new__
+            if getattr(self, "_ready", False):
+                return Response(200, {"ready": True})
+            return Response(503, {"ready": False,
+                                  "message": "serving warm-up running"})
 
         @r.post("/reload")
         def reload(request: Request) -> Response:
@@ -1182,23 +1264,37 @@ class PredictionServer:
         algorithms, models = self.algorithms, self.models
 
         def run() -> None:
-            if not self.http.wait_started(60.0):
-                logger.warning(
-                    "serving warmup skipped: server did not bind within "
-                    "60s (queries will compile on demand if it ever does)")
-                return
-            t0 = time.perf_counter()
-            self._warm_models(algorithms, models)
-            logger.info("serving warmup done in %.1fs",
-                        time.perf_counter() - t0)
+            try:
+                if not self.http.wait_started(60.0):
+                    logger.warning(
+                        "serving warmup skipped: server did not bind "
+                        "within 60s (queries will compile on demand if it "
+                        "ever does)")
+                    return
+                t0 = time.perf_counter()
+                self._warm_models(algorithms, models)
+                logger.info("serving warmup done in %.1fs",
+                            time.perf_counter() - t0)
+            finally:
+                self._ready = True
 
         threading.Thread(target=run, daemon=True,
                          name="pio-serving-warmup").start()
+
+    def _watch_gc(self) -> None:
+        """Serving starts: book the collector's pauses from here on
+        (pio_gc_pause_seconds, the gc.pause annotation) until stop()."""
+        with self._lock:
+            held = getattr(self, "_gc_hook_held", False)
+            self._gc_hook_held = True
+        if not held:
+            obs_trace.acquire_gc_hook()
 
     def start_background(self) -> int:
         self.load_models()
         self.undeploy_existing()
         port = self.http.start_background()
+        self._watch_gc()
         self._warmup_async()
         logger.info("PredictionServer started on %s:%d", self.config.ip, port)
         return port
@@ -1206,6 +1302,7 @@ class PredictionServer:
     async def serve_forever(self) -> None:
         self.load_models()
         self.undeploy_existing()
+        self._watch_gc()
         self._warmup_async()
         await self.http.serve_forever()
 
@@ -1215,6 +1312,10 @@ class PredictionServer:
         with self._lock:
             held = getattr(self, "_mips_daemon_held", False)
             self._mips_daemon_held = False
+            gc_held = getattr(self, "_gc_hook_held", False)
+            self._gc_hook_held = False
+        if gc_held:
+            obs_trace.release_gc_hook()
         if held:
             try:
                 from incubator_predictionio_tpu.ops import mips_daemon

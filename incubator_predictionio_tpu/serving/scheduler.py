@@ -60,7 +60,11 @@ Exported series: ``pio_serve_batch_size`` (pow2 buckets — the fused
 width distribution, the fleet bench's ``fleet_batch_p50`` source),
 ``pio_serve_queue_wait_seconds``,
 ``pio_serve_shed_total{tenant,reason}`` (tenant values come from the
-bounded registry — the ``unscoped-tenant-metric`` lint contract).
+bounded registry — the ``unscoped-tenant-metric`` lint contract), and
+through ``obs/trace.stage`` the dispatcher's own cycle:
+``pio_serve_phase_seconds_total{phase}`` with ``serve.wait`` and
+``serve.dispatch`` tiling each dispatcher thread's time
+(docs/observability.md "The dispatcher's cycle").
 """
 
 from __future__ import annotations
@@ -71,6 +75,7 @@ import inspect
 import math
 import os
 import threading
+import time
 import weakref
 from collections import OrderedDict, deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
@@ -348,6 +353,9 @@ class BatchScheduler:
         #: dispatch-slot caps bind (see _slot_caps_locked)
         self._t_last_submit: Dict[str, float] = {}
         self._stopped = False
+        #: dispatches popped so far; a dispatch's number is its ``id`` in
+        #: the serve.dispatch annotation
+        self._dispatch_seq = 0
         self.shed_count = 0
         self.shed_by_tenant: Dict[str, int] = {}
         self._n_workers = max(int(workers), 1)
@@ -739,24 +747,50 @@ class BatchScheduler:
 
     def _run(self) -> None:
         while True:
-            with self._cv:
-                while not self._stopped and self._pick_locked() is None:
-                    self._cv.wait(0.5)
+            # serve.wait and serve.dispatch tile this thread's life (the
+            # phases of pio_serve_phase_seconds_total sum to its elapsed
+            # time): the wait also holds the pick and the pop, a few
+            # microseconds, so that nothing falls between the two
+            with obs_trace.stage("serve.wait"):
+                taken = self._take()
+            if taken is None:
+                return
+            self._dispatch(*taken)
+
+    def _take(self) -> Optional[Tuple[str, str, _EngineQueue,
+                                      List[_Pending], int, int]]:
+        """Block until a queue may be picked, pop its batch: ``(tenant,
+        engine, queue, batch, dispatch id, the rung it was taken at)``,
+        or None once stopped and drained."""
+        with self._cv:
+            while True:
                 picked = self._pick_locked()
-                if picked is None:
-                    if self._stopped:
-                        return
-                    continue
-                (tenant, engine), q = picked
-                now = self._clock()
-                oldest_age = now - q.items[0].t_enq
-                take, q.rung = plan_dispatch(
-                    len(q.items), q.rung, oldest_age, self.cap,
-                    self.wait_bound_s)
-                batch = [q.items.popleft() for _ in range(take)]
-                q.in_flight += 1
-                self._service[tenant] = self._service.get(tenant, 0.0) \
-                    + take / self._weight(tenant)
+                if picked is not None:
+                    break
+                if self._stopped:
+                    return None
+                self._cv.wait(0.5)
+            (tenant, engine), q = picked
+            now = self._clock()
+            oldest_age = now - q.items[0].t_enq
+            rung = q.rung
+            take, q.rung = plan_dispatch(
+                len(q.items), q.rung, oldest_age, self.cap,
+                self.wait_bound_s)
+            batch = [q.items.popleft() for _ in range(take)]
+            q.in_flight += 1
+            self._service[tenant] = self._service.get(tenant, 0.0) \
+                + take / self._weight(tenant)
+            self._dispatch_seq += 1
+            return tenant, engine, q, batch, self._dispatch_seq, rung
+
+    def _dispatch(self, tenant: str, engine: str, q: _EngineQueue,
+                  batch: List[_Pending], seq: int, rung: int) -> None:
+        # the parent of every phase of one dispatch, and an annotation
+        # only: what it books, as phase "other", is its own time outside
+        # them (lock hand-offs, note_wall, the histograms below)
+        with obs_trace.stage("serve.dispatch", phase="other", id=seq,
+                             batch=len(batch), rung=rung):
             t0 = self._clock()
             for p in batch:
                 _QUEUE_WAIT.observe(max(t0 - p.t_enq, 0.0))
@@ -792,8 +826,16 @@ class BatchScheduler:
                 # a slot-capped tenant just freed a slot: wake the idle
                 # dispatcher the cap reserved, or it stalls a cv.wait
                 self._cv.notify()
-            for p, res in zip(batch, results):
-                if isinstance(res, Exception):
-                    p.fut.set_exception(res)
-                else:
-                    p.fut.set_result(res)
+            with obs_trace.stage("serve.complete"):
+                # one future a dispatch, each place in the batch in its
+                # turn, carries the instant it was resolved: the handler
+                # that awaits it books pio_serve_reply_lag_seconds, once
+                # per dispatch and not per query
+                stamped = batch[seq % len(batch)]
+                for p, res in zip(batch, results):
+                    if p is stamped:
+                        p.fut.resolved_at = time.perf_counter()
+                    if isinstance(res, Exception):
+                        p.fut.set_exception(res)
+                    else:
+                        p.fut.set_result(res)

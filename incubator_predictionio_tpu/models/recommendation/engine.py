@@ -773,9 +773,10 @@ class ALSAlgorithm(Algorithm):
                 top_s, top_i = host_batch_top_k(
                     np_users[rows] @ np_items.T, k)
             return [(top_s[b], top_i[b]) for b in range(len(rows))]
-        # launch returns once the program is enqueued; the fetch then
-        # waits for the device and copies down, so the device's busy time
-        # lies inside serve.fetch
+        # the launch is one call into the runtime (the batch's rows ride
+        # up as the scoring program's own argument) and returns once the
+        # program is enqueued; the fetch then waits for the device and
+        # copies down, so the device's busy time lies inside serve.fetch
         with stage("serve.launch"):
             on_device = batch_score_top_k(
                 model.user_factors, model.item_factors, rows, k)

@@ -411,8 +411,14 @@ def batch_score_top_k(
             user_factors, item_factors, mips_index, rows_np, k_pad)
     _mips.book_exhaustive(int(pad) * int(item_factors.shape[0]))
     _pt0 = _profile.t0()  # None on the PIO_PROFILE=0 default hot path
+    # ONE call into the runtime launches the dispatch: the padded int32
+    # rows go in as a host array and ride up on the jitted call's own
+    # argument path (a device array made first is a second dispatch
+    # through Python and a transfer waited for before the program is
+    # called: 0.27 of a 0.49 ms launch on the chip's host, PERF.md PR 27).
+    # The call returns once the program is enqueued, rows included.
     out = _batch_score_top_k_xla(user_factors, item_factors,
-                                 jnp.asarray(rows_np), k_pad,
+                                 rows_np, k_pad,
                                  valid_items=valid_items)
     _profile.record(
         _pt0, "serve", "serve_topk_batch",

@@ -230,23 +230,32 @@ def test_num_zero_returns_empty_on_both_paths(seeded_app):
     assert algo.predict(models[0], Query(user="uA1", num=0)).item_scores == ()
 
 
-def test_warmup_covers_the_lone_query_fast_path(seeded_app):
+@pytest.mark.parametrize("max_batch", [8, 16])
+def test_warmup_covers_every_live_width(seeded_app, max_batch):
     """A lone plain query rides the columnar fast path (batch_serve_json →
     batch_score_top_k at B=1), not predict(): deploy-time warmup must
     have compiled that rung too, or the first live query compiles on the
     dispatcher thread (0.2–0.3 s on a v5e — enough to push the latency
-    p99 over the serve SLO and make the scheduler shed the next burst)."""
+    p99 over the serve SLO and make the scheduler shed the next burst).
+    And the warm-up and the live dispatch call one function with one
+    kind of argument (the rows as a host array), so what was warmed is
+    what is dispatched: a batch of every size 1…max_batch leaves the
+    count of compiled serving variants where the warm-up left it."""
     from incubator_predictionio_tpu.ops import topk
 
     engine = RecommendationEngine().apply()
     models = engine.train(RuntimeContext(), engine_params())
     algo = engine.algorithms(engine_params())[0]
     object.__setattr__(models[0], "_np_cache", False)  # device path
-    algo.warmup(models[0], max_batch=8)
+    algo.warmup(models[0], max_batch=max_batch)
     warm = topk.serve_compile_cache_size()
-    for width in (1, 2, 3, 8):
-        docs = [{"user": "uA1", "num": 10}] * width   # the warmed k
+    assert warm > 0
+    users = ["uA%d" % i for i in range(8)] + ["uB%d" % i for i in range(8)]
+    for width in range(1, max_batch + 1):
+        docs = [{"user": u, "num": 10} for u in users[:width]]  # warmed k
         assert all(algo.batch_serve_json(models[0], docs))
+        assert topk.serve_compile_cache_size() == warm, \
+            f"a batch of {width} compiled after warmup"
     assert algo.predict(models[0], Query(user="uA1", num=10)).item_scores
     assert topk.serve_compile_cache_size() == warm, \
-        "a live width compiled after warmup"
+        "the singleton path compiled after warmup"

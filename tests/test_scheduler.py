@@ -328,6 +328,40 @@ def test_warm_ladder_serves_with_zero_recompiles():
         s.stop()
 
 
+@pytest.mark.parametrize("k", [1, 10, 16])
+@pytest.mark.parametrize("width", range(1, 17))
+def test_rows_as_the_calls_argument_score_as_a_device_array_did(width, k):
+    """``batch_score_top_k`` hands the padded int32 rows to the jitted
+    program as a host array (one call launches the dispatch). Whatever
+    the caller's rows are — a Python list, an int64 array, an int32
+    array — the packed result is bitwise what the program returns for
+    the same padded rows made into a device array first and passed in:
+    the two-call launch, written out here as the plain reference."""
+    import numpy as np
+
+    import jax.numpy as jnp
+
+    from incubator_predictionio_tpu.ops import topk
+
+    uf = jnp.asarray(np.random.default_rng(2).normal(
+        size=(64, 8)).astype(np.float32))
+    itf = jnp.asarray(np.random.default_rng(3).normal(
+        size=(48, 8)).astype(np.float32))
+    rows = [int(r) for r in
+            np.random.default_rng(100 * width + k).integers(0, 64, width)]
+    rung = topk.next_pow2(width)
+    padded = np.asarray(rows + [rows[0]] * (rung - width), np.int32)
+    k_pad = topk.next_pow2(k)
+    want = np.asarray(topk._batch_score_top_k_xla(
+        uf, itf, jnp.asarray(padded), k_pad))
+    assert want.shape == (2, rung, k_pad)
+    for given in (rows, np.asarray(rows, np.int64),
+                  np.asarray(rows, np.int32)):
+        got = np.asarray(topk.batch_score_top_k(uf, itf, given, k))
+        assert got.dtype == want.dtype
+        assert got.tobytes() == want.tobytes(), type(given)
+
+
 # ---------------------------------------------------------------------------
 # metrics
 # ---------------------------------------------------------------------------

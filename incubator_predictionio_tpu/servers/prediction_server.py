@@ -96,13 +96,14 @@ _STALENESS = obs_metrics.REGISTRY.gauge(
     "seconds since the served engine instance finished training "
     "(scrape-time snapshot)")
 #: an answer the dispatcher has finished waits for the event loop to pick
-#: it up (asyncio.wrap_future): one query of each dispatch is sampled, on
-#: the loop's thread (serving/scheduler.py stamps it). Steps of at most
-#: 1.5, not doubling: its p95 is read from the buckets
+#: it up (one hand-over a dispatch, serving/scheduler.py): one query of
+#: each dispatch is sampled, on the loop's thread (the scheduler stamps
+#: it at the hand-over). Steps of at most 1.5, not doubling: its p95 is
+#: read from the buckets
 _REPLY_LAG = obs_metrics.REGISTRY.histogram(
     "pio_serve_reply_lag_seconds",
-    "future resolved on the dispatcher's thread to handler resumed on "
-    "the event loop's, one query a dispatch",
+    "answer handed over on the dispatcher's thread to handler resumed "
+    "on the event loop's, one query a dispatch",
     buckets=obs_metrics.geometric_buckets(50e-6, 1.0))
 #: what GET /ready answers from, the load-balancer probe, read off the
 #: newest server at scrape time: 0 until the deploy's warm-up thread has
@@ -1047,13 +1048,16 @@ class PredictionServer:
                             "x-pio-priority", "0"))
                     except ValueError:
                         prio = 0
+                    # a future of this loop: the dispatcher hands a
+                    # whole batch's answers over in one call into it
                     fut = self._batcher.submit(
                         request.body, priority=prio,
-                        engine=self.config.engine_id, tenant=tenant)
-                    result = await asyncio.wrap_future(fut)
+                        engine=self.config.engine_id, tenant=tenant,
+                        loop=asyncio.get_running_loop())
+                    result = await fut
                     t_resolved = getattr(fut, "resolved_at", None)
                     if t_resolved is not None:
-                        # this dispatch's sample: resolved on the
+                        # this dispatch's sample: handed over on the
                         # dispatcher's thread → resumed here, on the
                         # loop's; inside the client's latency and in no
                         # other series. It stands for the dispatch, so no

@@ -69,6 +69,7 @@ through ``obs/trace.stage`` the dispatcher's own cycle:
 
 from __future__ import annotations
 
+import asyncio
 import concurrent.futures
 import dataclasses
 import inspect
@@ -103,6 +104,11 @@ _SHED = obs_metrics.REGISTRY.counter(
     "admission quota was full; evicted = displaced by a higher-"
     "priority arrival; shutdown = scheduler stopping)",
     labels=("tenant", "reason"))
+_HANDOVERS = obs_metrics.REGISTRY.counter(
+    "pio_serve_reply_handovers_total",
+    "calls into an event loop from a dispatcher thread, each carrying "
+    "every answer of one dispatch that the loop's handlers wait for: "
+    "one a dispatch (against pio_serve_batch_size_count)")
 _COMPILE_CACHE = obs_metrics.REGISTRY.gauge(
     "pio_serve_compile_cache_size",
     "compiled serving-dispatch variants resident (ops/topk ladder) — "
@@ -215,12 +221,48 @@ def plan_dispatch(depth: int, rung: int, oldest_age_s: float,
     return take, rung
 
 
+def _settle(fut: Any, res: Any) -> None:
+    """Resolve one waiter of either kind with its result or exception.
+    One that is done already (a client that hung up cancelled it) is
+    skipped: it must not cost the rest of its batch their answers."""
+    if fut.done():
+        return
+    if isinstance(res, Exception):
+        fut.set_exception(res)
+    else:
+        fut.set_result(res)
+
+
+def _deliver(answers: List[Tuple[Any, Any]]) -> None:
+    """On the loop's thread: every waiter of one hand-over, in order."""
+    for fut, res in answers:
+        _settle(fut, res)
+
+
+def _hand_over(loop: "asyncio.AbstractEventLoop",
+               answers: List[Tuple[Any, Any]]) -> bool:
+    """One call into ``loop`` from another thread (one write to its
+    self-pipe, one hand-off of the interpreter lock) for all of
+    ``answers``. False where the loop was closed under a stopping
+    server: nobody waits there any more, and the caller's thread lives."""
+    try:
+        loop.call_soon_threadsafe(_deliver, answers)
+    except RuntimeError:
+        return False
+    return True
+
+
 @dataclasses.dataclass
 class _Pending:
     body: Any
-    fut: "concurrent.futures.Future"
+    #: a concurrent.futures.Future (a caller on a plain thread), or a
+    #: future of ``loop`` where the caller gave one
+    fut: Any
     t_enq: float
     priority: int
+    #: the event loop whose handler waits (None: a plain thread) — its
+    #: future may only be touched on that loop's thread
+    loop: Optional["asyncio.AbstractEventLoop"] = None
     #: the submitting request's ambient trace ID (None outside a
     #: request) — the dispatch loop re-installs ONE member's trace
     #: around handle_batch so the latency histogram's exemplar
@@ -459,15 +501,24 @@ class BatchScheduler:
     def submit(self, body: Any, priority: int = 0,
                engine: str = "default",
                tenant: str = tenancy.DEFAULT_TENANT,
-               ) -> "concurrent.futures.Future":
+               loop: Optional["asyncio.AbstractEventLoop"] = None,
+               ) -> Any:
         """Enqueue one query body → Future of its result. ``priority``
         orders only the SHED decision (higher survives longer), never
         dispatch order — admitted requests stay FIFO so no admitted
         query starves behind a later high-priority one. The shed
         projection reads only THIS tenant's queue and p99, and eviction
         victims come only from at-or-over-fair-share tenants: a noisy
-        neighbor sheds its own traffic, never a victim's."""
-        fut: "concurrent.futures.Future" = concurrent.futures.Future()
+        neighbor sheds its own traffic, never a victim's.
+
+        A caller on a plain thread gets a ``concurrent.futures.Future``.
+        A handler on an event loop passes that ``loop`` (it calls from
+        the loop's own thread) and awaits what it gets, a future of that
+        loop: a dispatch then hands all its answers for the loop over in
+        ONE ``call_soon_threadsafe`` and they are resolved on the loop's
+        thread, not one wake-up of the loop a query on the dispatcher's."""
+        fut: Any = (concurrent.futures.Future() if loop is None
+                    else loop.create_future())
         now = self._clock()
         shed_exc: Optional[ShedError] = None
         victim: Optional[_Pending] = None
@@ -533,7 +584,7 @@ class BatchScheduler:
                     self._service[tenant] = max(
                         self._service.get(tenant, 0.0), floor)
                 q.items.append(_Pending(body, fut, now, int(priority),
-                                        obs_trace.current_trace_id()))
+                                        loop, obs_trace.current_trace_id()))
                 self._cv.notify()
             retry_hint = q.projected_wait_s(self.cap)
             # counted under the lock: submit runs on the HTTP thread
@@ -547,8 +598,11 @@ class BatchScheduler:
         if victim is not None:
             _SHED.labels(tenant=tenancy.get_registry().label(victim_tenant),
                          reason="evicted").inc()
-            victim.fut.set_exception(
-                ShedError(retry_hint, reason="evicted"))
+            evicted = ShedError(retry_hint, reason="evicted")
+            if victim.loop is None:
+                _settle(victim.fut, evicted)
+            else:       # its handler waits on a loop that may be another's
+                _hand_over(victim.loop, [(victim.fut, evicted)])
         if shed_exc is not None:
             _SHED.labels(tenant=tenancy.get_registry().label(tenant),
                          reason=shed_exc.reason).inc()
@@ -827,15 +881,22 @@ class BatchScheduler:
                 # dispatcher the cap reserved, or it stalls a cv.wait
                 self._cv.notify()
             with obs_trace.stage("serve.complete"):
-                # one future a dispatch, each place in the batch in its
-                # turn, carries the instant it was resolved: the handler
-                # that awaits it books pio_serve_reply_lag_seconds, once
-                # per dispatch and not per query
-                stamped = batch[seq % len(batch)]
+                by_loop: Dict[Any, List[Tuple[Any, Any]]] = {}
                 for p, res in zip(batch, results):
-                    if p is stamped:
-                        p.fut.resolved_at = time.perf_counter()
-                    if isinstance(res, Exception):
-                        p.fut.set_exception(res)
+                    if p.loop is None:
+                        _settle(p.fut, res)
                     else:
-                        p.fut.set_result(res)
+                        by_loop.setdefault(p.loop, []).append((p.fut, res))
+                # one waiter a dispatch, each place in the batch in its
+                # turn, carries the instant its answer left this thread:
+                # the handler that awaits it books
+                # pio_serve_reply_lag_seconds, once per dispatch and not
+                # per query (a plain thread's future has no such reader)
+                stamped = batch[seq % len(batch)]
+                if stamped.loop is not None:
+                    stamped.fut.resolved_at = time.perf_counter()
+                # the handlers' answers: one call a loop (the server has
+                # one), whatever the batch's length
+                for loop, answers in by_loop.items():
+                    if _hand_over(loop, answers):
+                        _HANDOVERS.inc()

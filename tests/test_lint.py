@@ -379,8 +379,8 @@ class Server:
     async def handle_query(self, request):
         # the sanctioned seam: enqueue, let the scheduler coalesce the
         # in-flight queries into one fused dispatch
-        return await asyncio.wrap_future(
-            self.batcher.submit(request.body))
+        return await self.batcher.submit(
+            request.body, loop=asyncio.get_running_loop())
 """,
     ),
     "exhaustive-scan": (

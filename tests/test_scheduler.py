@@ -15,9 +15,15 @@ decision):
   width the scheduler can choose from the jit cache
   (``ops.topk.serve_compile_cache_size`` — the foldin-cache pin's
   serving twin)
+- one hand-over to the event loop a dispatch: waiters that came with
+  their loop get a whole batch's answers in ONE ``call_soon_threadsafe``
+  and are resolved on the loop's thread; a waiter that hung up, a loop
+  that closed, a shed or an eviction cost nobody else an answer
 """
 
+import asyncio
 import threading
+import time
 
 import pytest
 
@@ -281,6 +287,296 @@ def test_cold_queue_never_sheds():
         assert s.shed_count == 0
     finally:
         s.stop()
+
+
+# ---------------------------------------------------------------------------
+# one hand-over to the event loop a dispatch
+# ---------------------------------------------------------------------------
+
+class _LoopThread:
+    """An event loop on a thread of its own, as the server's is. Counts
+    the calls into it that come from a dispatcher thread (the test's own
+    ``run`` calls into it too, from the test's thread)."""
+
+    def __init__(self):
+        self.loop = asyncio.new_event_loop()
+        self.handovers = 0      # calls begun
+        self.queued = 0         # calls whose callback the loop now holds
+        self.handed = threading.Event()
+        inner = self.loop.call_soon_threadsafe
+
+        def counted(callback, *args, **kw):
+            from_dispatcher = threading.current_thread().name.startswith(
+                "pio-serve-sched-")
+            if from_dispatcher:
+                self.handovers += 1
+            handle = inner(callback, *args, **kw)
+            if from_dispatcher:
+                self.queued += 1
+                self.handed.set()
+            return handle
+
+        self.loop.call_soon_threadsafe = counted
+        self.thread = threading.Thread(target=self.loop.run_forever,
+                                       daemon=True)
+        self.thread.start()
+
+    def run(self, coro, timeout=10.0):
+        return asyncio.run_coroutine_threadsafe(
+            coro, self.loop).result(timeout)
+
+    def submit(self, s, bodies, **kw):
+        """``s.submit`` for each body ON the loop's thread, as a handler
+        calls it: the loop's futures, in order."""
+        async def go():
+            return [s.submit(b, loop=self.loop, **kw) for b in bodies]
+        return self.run(go())
+
+    def gather(self, futs, timeout=10.0):
+        """Results (an exception as a value), awaited on the loop."""
+        async def go():
+            return await asyncio.gather(*futs, return_exceptions=True)
+        return self.run(go(), timeout)
+
+    def close(self):
+        if not self.loop.is_closed():
+            self.loop.call_soon_threadsafe(self.loop.stop)
+            self.thread.join(5)
+            self.loop.close()
+
+
+@pytest.fixture
+def on_loop():
+    lt = _LoopThread()
+    yield lt
+    lt.close()
+
+
+class _HeldFirst:
+    """Holds the first dispatch in the handler until ``gate`` is set, so
+    that a backlog can build behind it; every batch's bodies are kept. A
+    body that starts with ``!`` fails alone, as a per-query exception."""
+
+    def __init__(self):
+        self.gate = threading.Event()
+        self.in_handler = threading.Event()
+        self.batches = []
+
+    def __call__(self, bodies):
+        if not self.in_handler.is_set():
+            self.in_handler.set()
+            assert self.gate.wait(10)
+        self.batches.append(list(bodies))
+        return [ValueError(b.decode()) if b.startswith(b"!") else b
+                for b in bodies]
+
+
+def _counts(handed_at_least=0.0):
+    """(pio_serve_reply_handovers_total, dispatches). The dispatcher books
+    a hand-over after the call, so its waiter may be here first: waits
+    for the counter to reach ``handed_at_least``."""
+    from incubator_predictionio_tpu.obs import metrics as obs_metrics
+
+    handed = obs_metrics.REGISTRY.get("pio_serve_reply_handovers_total")
+    deadline = time.monotonic() + 5
+    while handed.value < handed_at_least and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return (handed.value,
+            obs_metrics.REGISTRY.get("pio_serve_batch_size").count)
+
+
+def _one_batch_behind_a_held_one(s, clock, handler, on_loop, bodies,
+                                 plain=()):
+    """Holds one dispatch (a waiter of the loop), queues ``bodies`` from
+    the loop and ``plain`` from this thread behind it and lets the age
+    bound take them all as ONE batch. Returns (the held waiter, the
+    loop's waiters, the plain-thread futures), the gate still closed."""
+    held, = on_loop.submit(s, [b"held"])
+    assert handler.in_handler.wait(5)
+    futs = on_loop.submit(s, bodies)
+    plain_futs = [s.submit(b) for b in plain]
+    clock.advance(1.0)      # the backlog is past the bound: taken whole
+    return held, futs, plain_futs
+
+
+@pytest.mark.parametrize("n", [1, 2, 7, 64])
+def test_a_batch_from_one_loop_is_one_call_into_it(on_loop, n):
+    clock = FakeClock()
+    handler = _HeldFirst()
+    s = BatchScheduler(handler, 64, clock=clock, shed=False,
+                       wait_bound_s=0.25)
+    try:
+        handed0, batches0 = _counts()
+        bodies = [b"%d" % i for i in range(n)]
+        held, futs, _ = _one_batch_behind_a_held_one(
+            s, clock, handler, on_loop, bodies)
+        assert all(isinstance(f, asyncio.Future) for f in futs)
+        assert on_loop.handovers == 0
+        handler.gate.set()
+        assert on_loop.gather([held] + futs) == [b"held"] + bodies
+        assert handler.batches == [[b"held"], bodies]
+        # two dispatches (1 and n waiters): two calls into the loop, and
+        # the counter follows the dispatches', not the queries'
+        assert on_loop.handovers == 2
+        handed1, batches1 = _counts(handed0 + 2)
+        assert handed1 - handed0 == batches1 - batches0 == 2
+    finally:
+        s.stop()
+
+
+def test_answers_and_exceptions_reach_their_own_waiter_in_order(on_loop):
+    clock = FakeClock()
+    handler = _HeldFirst()
+    s = BatchScheduler(handler, 64, clock=clock, shed=False,
+                       wait_bound_s=0.25)
+    try:
+        handed0, _b = _counts()
+        # loop waiters and plain-thread waiters in one batch, two of the
+        # seven failing alone
+        held, futs, plain = _one_batch_behind_a_held_one(
+            s, clock, handler, on_loop, [b"a", b"!b", b"c", b"d"],
+            plain=[b"p", b"!q", b"r"])
+        order = []
+
+        async def watch():
+            for i, f in enumerate(futs):
+                f.add_done_callback(lambda _f, i=i: order.append(i))
+        on_loop.run(watch())
+        handler.gate.set()
+        got = on_loop.gather(futs)
+        assert handler.batches[1] == [b"a", b"!b", b"c", b"d",
+                                      b"p", b"!q", b"r"]
+        assert got[0] == b"a" and got[2:] == [b"c", b"d"]
+        assert isinstance(got[1], ValueError) and str(got[1]) == "!b"
+        assert order == [0, 1, 2, 3]
+        # the plain-thread callers of the same batch: as ever
+        assert plain[0].result(10) == b"p" and plain[2].result(10) == b"r"
+        with pytest.raises(ValueError, match="!q"):
+            plain[1].result(10)
+        assert all(not isinstance(f, asyncio.Future) for f in plain)
+        # the mixed batch still cost its loop one call (and the held one)
+        assert on_loop.handovers == 2
+        assert _counts(handed0 + 2)[0] - handed0 == 2
+    finally:
+        s.stop()
+
+
+@pytest.mark.parametrize("when", ["before-the-hand-over",
+                                  "between-hand-over-and-callback"])
+def test_a_waiter_that_hung_up_costs_nobody_else_an_answer(on_loop, when):
+    clock = FakeClock()
+    handler = _HeldFirst()
+    s = BatchScheduler(handler, 64, clock=clock, shed=False,
+                       wait_bound_s=0.25)
+    try:
+        bodies = [b"%d" % i for i in range(7)]
+        held, futs, _ = _one_batch_behind_a_held_one(
+            s, clock, handler, on_loop, bodies)
+
+        async def hang_up(fut):
+            fut.cancel()
+        on_loop.run(hang_up(held))      # its dispatch is still running
+        if when == "before-the-hand-over":
+            on_loop.run(hang_up(futs[3]))
+            handler.gate.set()
+        else:
+            on_loop.handed.clear()
+
+            async def hold_the_loop_then_hang_up():
+                # the loop's thread stands still until the dispatcher has
+                # queued the batch's callback behind this one
+                handler.gate.set()
+                while on_loop.queued < 2:
+                    assert on_loop.handed.wait(10)
+                    on_loop.handed.clear()
+                assert not any(f.done() for f in futs)
+                futs[3].cancel()
+            on_loop.run(hold_the_loop_then_hang_up(), 30)
+        got = on_loop.gather(futs)
+        assert isinstance(got[3], asyncio.CancelledError)
+        assert got[:3] + got[4:] == bodies[:3] + bodies[4:]
+        # the dispatcher's thread lives: the next batch is answered
+        assert on_loop.gather(on_loop.submit(s, [b"next"])) == [b"next"]
+        assert s.submit(b"plain").result(10) == b"plain"
+        assert all(t.is_alive() for t in s._threads)
+    finally:
+        s.stop()
+
+
+def test_a_loop_closed_before_the_hand_over_leaves_the_dispatcher_alive():
+    clock = FakeClock()
+    handler = _HeldFirst()
+    s = BatchScheduler(handler, 64, clock=clock, shed=False,
+                       wait_bound_s=0.25)
+    gone = _LoopThread()
+    try:
+        handed0, batches0 = _counts()
+        _one_batch_behind_a_held_one(s, clock, handler, gone,
+                                     [b"0", b"1"], plain=[])
+        gone.close()                    # a server stopping under its queue
+        handler.gate.set()
+        deadline = time.monotonic() + 10
+        while len(handler.batches) < 2 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        # the plain-thread caller behind them is served by the same thread
+        assert s.submit(b"after").result(10) == b"after"
+        assert all(t.is_alive() for t in s._threads)
+        assert handler.batches[:2] == [[b"held"], [b"0", b"1"]]
+        handed1, batches1 = _counts()
+        assert batches1 - batches0 == 3
+        assert handed1 == handed0       # no call went through
+    finally:
+        gone.close()
+        s.stop()
+
+
+def test_shed_quota_and_evicted_waiters_of_a_loop_get_their_shed_error(
+        on_loop):
+    clock = FakeClock()
+    handler = _GatedHandler(clock, wall_s=0.2)
+    s = BatchScheduler(handler, 4, clock=clock, shed=True, slo_s=0.5,
+                       p99_fn=lambda: 0.1, wait_bound_s=0.0,
+                       tenant_quotas={"capped": 1})
+    try:
+        assert on_loop.gather(on_loop.submit(s, [b"w"])) == [b"w"]
+        inflight, = on_loop.submit(s, [b"0"])
+        assert handler.in_handler.wait(5)
+        low = on_loop.submit(s, [b"%d" % i for i in range(4)])
+        # the overload point of test_shed_then_recover_flip: the arrival
+        # sheds, failed on its own (the loop's) thread inside submit
+        shed, = on_loop.submit(s, [b"last"])
+        assert shed.done()
+        err, = on_loop.gather([shed])
+        assert isinstance(err, ShedError) and err.reason == "overload"
+        assert err.status == 503 and int(err.headers["Retry-After"]) >= 1
+        # a higher-priority arrival from a PLAIN thread evicts a waiter of
+        # the loop: failed on the loop's thread, by one call into it
+        handed0, _b = _counts()
+        vip = s.submit(b"vip", priority=5)
+        got = on_loop.gather(low[:1])
+        assert isinstance(got[0], ShedError) and got[0].reason == "evicted"
+        assert int(got[0].headers["Retry-After"]) >= 1
+        assert not any(f.done() for f in low[1:]) and not vip.done()
+        assert _counts()[0] == handed0  # not a dispatcher's hand-over
+        # the tenant's own bound, whatever the load
+        first, second = on_loop.submit(s, [b"q1", b"q2"], tenant="capped")
+        err, = on_loop.gather([second])
+        assert isinstance(err, ShedError) and err.reason == "quota"
+        handler.gate.set()
+        assert on_loop.gather([inflight] + low[1:] + [first]) == [
+            b"0", b"1", b"2", b"3", b"q1"]
+        assert vip.result(10) == b"vip"
+        assert s.shed_count == 3
+    finally:
+        s.stop()
+
+
+def test_a_stopped_scheduler_fails_a_loop_waiter_at_once(on_loop):
+    s = BatchScheduler(lambda bodies: bodies, 4, shed=False)
+    s.stop()
+    fut, = on_loop.submit(s, [b"late"])
+    err, = on_loop.gather([fut])
+    assert getattr(err, "status", None) == 503
 
 
 # ---------------------------------------------------------------------------

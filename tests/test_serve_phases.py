@@ -7,8 +7,9 @@
 - through a real PredictionServer on a tiny ALS model: which phases move
   on the device path and on the host-copy path, the reply-lag histogram
   (one sample a dispatch, however many queries it fused, and no request's
-  trace as its exemplar), the collector's pauses, ``/ready`` and the
-  device-memory gauges;
+  trace as its exemplar), the one hand-over to the loop a dispatch and
+  the 503 a shed, evicted or over-quota handler sends, the collector's
+  pauses, ``/ready`` and the device-memory gauges;
 - the same ``stage`` calls land in a ``jax.profiler`` trace as nested
   annotations on one host line.
 """
@@ -253,7 +254,8 @@ def test_phases_that_move_on_each_scoring_path(als_server, monkeypatch,
     _wait_ready(port)                   # the warm-up books phases too
     lag = obs_metrics.REGISTRY.get("pio_serve_reply_lag_seconds")
     batches = obs_metrics.REGISTRY.get("pio_serve_batch_size")
-    lag0, batches0 = lag.count, batches.count
+    handed = obs_metrics.REGISTRY.get("pio_serve_reply_handovers_total")
+    lag0, batches0, handed0 = lag.count, batches.count, handed.value
     before = phase_seconds()
     for n in range(20):
         # num=10 is the shape the warm-up compiled: a compile on the live
@@ -277,8 +279,10 @@ def test_phases_that_move_on_each_scoring_path(als_server, monkeypatch,
         assert on >= common | {"host_score"}
         assert moved.get("launch", 0.0) == 0.0
         assert moved.get("fetch", 0.0) == 0.0
-    # one query at a time: twenty dispatches, a reply-lag sample each
+    # one query at a time: twenty dispatches, a reply-lag sample and a
+    # hand-over to the loop each
     assert lag.count - lag0 == batches.count - batches0 == 20
+    assert _reaches(lambda: handed.value - handed0, 20, 5.0)
 
 
 def test_reply_lag_is_sampled_once_a_dispatch(als_server, caplog):
@@ -338,6 +342,106 @@ def test_reply_lag_is_sampled_once_a_dispatch(als_server, caplog):
         "pio_serve_reply_lag_seconds_bucket")]
     assert all("#" not in ln for ln in text.splitlines() if ln.startswith(
         "pio_serve_reply_lag_seconds_bucket"))
+
+
+def _post_raw(port, body, headers=None, out=None, key=None):
+    """(status, headers, body text) of one query, an error's too."""
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/queries.json",
+        data=json.dumps(body).encode(),
+        headers={"Content-Type": "application/json", **(headers or {})})
+    try:
+        with urllib.request.urlopen(req, timeout=30) as resp:
+            got = resp.status, dict(resp.headers), resp.read().decode()
+    except urllib.error.HTTPError as e:
+        got = e.code, dict(e.headers), e.read().decode()
+    if out is not None:
+        out[key] = got
+    return got
+
+
+def _reaches(read, want, limit_s=10.0):
+    deadline = time.perf_counter() + limit_s
+    while read() != want and time.perf_counter() < deadline:
+        time.sleep(0.005)
+    return read() == want
+
+
+def test_a_fused_batch_is_one_hand_over_and_a_shed_handler_sends_503(
+        als_server):
+    ps = als_server()
+    port = ps.start_background()
+    _wait_ready(port)
+    batches = obs_metrics.REGISTRY.get("pio_serve_batch_size")
+    handed = obs_metrics.REGISTRY.get("pio_serve_reply_handovers_total")
+    sched = ps._batcher
+    # one query through, so that the queue knows a dispatch's wall
+    assert _post_raw(port, {"user": "u0", "num": 10})[0] == 200
+    inner = sched._handle_batch
+    gate, entered = threading.Event(), threading.Event()
+    widths = []
+
+    def gated(bodies, engine, tenant):
+        if not entered.is_set():
+            entered.set()
+            gate.wait(10)
+        widths.append(len(bodies))
+        return inner(bodies, engine, tenant)
+
+    sched._handle_batch = gated
+    sched._shed = False
+    sched.wait_bound_s = 0.05
+    batches0, handed0, shed0 = batches.count, handed.value, sched.shed_count
+    got, threads = {}, {}
+
+    def send(key, user, headers=None):
+        threads[key] = threading.Thread(target=_post_raw, args=(
+            port, {"user": user, "num": 10}, headers, got, key))
+        threads[key].start()
+
+    send("held", "u1")
+    assert entered.wait(10)
+    for n in range(3):
+        send(f"q{n}", f"u{n + 2}")
+    assert _reaches(sched.depth, 3)
+    # the projection is now over any objective: an arrival of the same
+    # priority is shed, one of a higher priority evicts a waiter of the
+    # lowest, and a tenant at its quota is refused whatever the load
+    sched._shed, sched.slo_s = True, 0.0
+    status, headers, text = _post_raw(port, {"user": "u5", "num": 10})
+    assert status == 503 and "overloaded" in text
+    assert int(headers["Retry-After"]) >= 1
+    assert headers["X-PIO-Queue-Depth"] == "3"
+    send("vip", "u6", {"X-PIO-Priority": "5"})
+    assert _reaches(lambda: sum(k in got for k in ("q0", "q1", "q2")), 1)
+    evicted, = [k for k in ("q0", "q1", "q2") if k in got]
+    status, headers, _text = got[evicted]
+    assert status == 503 and int(headers["Retry-After"]) >= 1
+    assert "X-PIO-Queue-Depth" in headers
+    assert _reaches(sched.depth, 3)     # two of the three, and the vip
+    sched._shed = False
+    sched.set_tenant_policy(quotas={"default": 3})
+    status, headers, _text = _post_raw(port, {"user": "u7", "num": 10})
+    assert status == 503 and int(headers["Retry-After"]) >= 1
+    sched.set_tenant_policy(quotas={})
+    time.sleep(0.06)                    # past the age bound: one batch
+    gate.set()
+    for t in threads.values():
+        t.join(30)
+        assert not t.is_alive()
+    answered = sorted(k for k, v in got.items() if v[0] == 200)
+    assert answered == sorted({"held", "vip", "q0", "q1", "q2"} - {evicted})
+    for k in answered:
+        assert json.loads(got[k][2])["itemScores"]
+    # the held dispatch and the three that rode one: two dispatches, two
+    # calls into the loop, neither the shed's nor the eviction's among them
+    assert widths == [1, 3]
+    assert batches.count - batches0 == 2
+    assert _reaches(lambda: handed.value - handed0, 2)
+    assert sched.shed_count - shed0 == 3
+    _status, text = _get(port, "/metrics")
+    assert _series(text, "pio_serve_reply_handovers_total")[""] \
+        == handed.value
 
 
 def test_ready_gc_pause_and_device_gauges(als_server):

@@ -52,7 +52,7 @@ import urllib.request
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 #: MovieLens-20M's shape (ratings.csv: 138,493 users, 26,744 movies,
-#: 20,000,263 ratings) — bench.py / README "north star". Widths (users,
+#: 20,000,263 ratings), the README's "north star". Widths (users,
 #: items, rank) are never cut; --ratings may cut the scale, and says so.
 ML20M = {"users": 138_493, "items": 26_744, "ratings": 20_000_000}
 #: the rehearsal's tiny shape (rank stays 128: widths are not cut)

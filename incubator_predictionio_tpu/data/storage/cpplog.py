@@ -454,7 +454,8 @@ class CppLogEvents(base.Events):
         self._shard_events: dict[int, int] = {}  # pio-lint: guarded-by(_gc_mu)
         # sub-metrics of the last full sharded scan (shard count, native
         # lock-held wall, merge/total walls — _merge_shards fills the
-        # same dict the bench reads), exported as gauges at scrape time
+        # same dict a caller's ``stats`` gets), exported as gauges at
+        # scrape time
         self._last_scan_stats: dict = {}
         # scrape-time bridge into the process registry: group-commit and
         # scan counters show up on every server's GET /metrics. Named
@@ -1203,7 +1204,7 @@ class CppLogEvents(base.Events):
         takes the full sharded scan, which then (re)seeds the cache at
         training scale.
 
-        cpplog-specific extras (the bench and the pipelined ingest path;
+        cpplog-specific extras (the pipelined ingest path and the tests;
         other backends ignore them): ``use_cache``/``seed_cache`` bypass
         the projection cache's read/write legs, ``stats`` (a dict) is
         filled with the scan sub-metrics (shard count, per-shard walls,
@@ -2226,7 +2227,7 @@ class CppLogEvents(base.Events):
         to each target shard CONCURRENTLY — ctypes releases the GIL, so
         the per-shard native appends (hashing + record rendering + the
         buffered write, all in C++) really overlap; this fan-out is the
-        multi-writer throughput win the bench measures. Returns
+        multi-writer throughput win. Returns
         (rc, ids) with ids in CALLER order (derived per shard from a
         shard-mixed seed). Caller holds the client lock; workers touch
         only pre-resolved handles and per-shard locks (lock order:

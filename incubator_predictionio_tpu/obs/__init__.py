@@ -4,8 +4,8 @@ The reference leaned on the implicit Spark UI plus ad-hoc bookkeeping
 (per-query latency in CreateServer.scala:426-428, per-app hourly ingest
 counters in Stats.scala:51-80); the rebuild had reproduced those
 fragments piecemeal (``utils/tracing.py`` phase walls, ``servers/
-stats.py`` counters, native group-commit/scan counters only the bench
-read). This package is the one coherent layer over all of them:
+stats.py`` counters, native group-commit/scan counters nothing
+exported). This package is the one coherent layer over all of them:
 
 - :mod:`.metrics` — a process-wide registry of Counter / Gauge /
   Histogram metrics, thread-safe and cheap enough for the serving hot

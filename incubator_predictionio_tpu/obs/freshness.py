@@ -51,8 +51,8 @@ from incubator_predictionio_tpu.utils import times
 FRESHNESS_BUCKETS = tuple(0.01 * (2.0 ** i) for i in range(24))
 
 #: the end-to-end promise: event append wall → first serve that used
-#: the folded vector (docs/observability.md; the freshness_p95 SLO and
-#: the bench's obs_freshness_p95_s both read this family)
+#: the folded vector (docs/observability.md; the freshness_p95 SLO
+#: reads this family)
 FRESHNESS_SECONDS = obs_metrics.REGISTRY.histogram(
     "pio_freshness_seconds",
     "end-to-end freshness: event appended to the log -> first "

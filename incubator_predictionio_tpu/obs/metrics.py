@@ -423,15 +423,6 @@ class _Metric:
             return self.quantile_over_children(q)
         return self._solo().quantile(q)
 
-    def total(self) -> float:
-        """Sum over every labeled child (counter/gauge families) — the
-        bench's registry snapshot collapses label sets with this."""
-        if self.kind == "histogram":
-            raise ValueError("total() is for counter/gauge; use sum/count")
-        with self._lock:
-            children = list(self._children.values())
-        return sum(c.value for c in children)
-
     def max_value(self) -> float:
         """Max over every labeled child (counter/gauge families) — the
         worst-of reading gauge SLOs evaluate (obs/slo.py): on a fleet-

@@ -1,16 +1,13 @@
 """Device-time / MFU attribution for the hot dispatch entry points.
 
-The bench computes MFU offline (analytic FLOPs over the measured warm
-wall); production had no live equivalent — device time was invisible.
-This module wraps the training, retrain, fold-in and serving dispatches
+Without it a deployment's device time is invisible. This module wraps the training, retrain, fold-in and serving dispatches
 with **block-until-ready wall deltas** plus known-FLOP counters, off by
 default and enabled with ``PIO_PROFILE=1``:
 
 - ``pio_device_seconds{op}`` — attributed device+dispatch wall,
 - ``pio_device_dispatches_total{op}`` — dispatches attributed,
 - ``pio_device_flops_total{op}`` — analytic useful FLOPs (padding waste
-  is *not* counted — it shows up as lower MFU, the honest convention
-  the bench uses),
+  is *not* counted — it shows up as lower MFU),
 - ``pio_mfu{phase}`` — the LAST dispatch's model-FLOP utilization in
   that phase against the device's published peak (:data:`PEAK_FLOPS`,
   one table keyed by ``device_kind``). A device the table does not

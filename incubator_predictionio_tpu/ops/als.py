@@ -1131,9 +1131,9 @@ def gather_source_rows(placement, side_gathered: str, mode: str) -> int:
     """Rows of the array a half-sweep's gather hands the solve — the
     FULL padded table under allgather, ONE slice under ring. This is
     the shape the fused kernel pins in VMEM, and the ONE rule shared by
-    :func:`_fused_sides_placed` and bench_shard's ``shard_fused_fits_*``
-    acceptance keys (a second copy of this math could silently drift
-    from what the trainer actually routes)."""
+    :func:`_fused_sides_placed` and the tests' fit arithmetic (a second
+    copy of this math could silently drift from what the trainer
+    actually routes)."""
     full = (placement.n_users_padded if side_gathered == "user"
             else placement.n_items_padded)
     return (placement.shard_rows(side_gathered) if mode == "ring"
@@ -1989,8 +1989,8 @@ def train_flops(
     warmstart: Optional[bool] = None,
 ) -> float:
     """THE analytic FLOP count of one training run — the single formula
-    the bench's offline MFU and the live ``pio_mfu{phase="train"}``
-    gauge (obs/profile.py) both divide by, so the two figures agree by
+    an offline MFU and the live ``pio_mfu{phase="train"}`` gauge
+    (obs/profile.py) both divide by, so the two figures agree by
     construction when the measured walls agree.
 
     Per half-sweep over ``nnz`` observations at rank K: the Gram batch

@@ -25,7 +25,7 @@ fall forward to when an index is registered:
 Exhaustive stays the FALLBACK and the ORACLE: ``PIO_SERVE_MIPS=off``,
 an unregistered table, a filtered query (``allowed_mask``), or a
 small-catalogue ``auto`` route all take the exhaustive path unchanged,
-and the recall@k gate (tests/test_mips.py, ``bench_mips``) compares the
+and the recall@k gate (tests/test_mips.py) compares the
 two-stage result against it.
 
 Speed-overlay seam: fold-in vectors published for ITEM-side keys are
@@ -88,7 +88,7 @@ NEG_INF = jnp.float32(-3.4e38)
 #: per stage — ``centroid`` (coarse centroid rows), ``coarse``
 #: (quantized candidate slots in probed buckets, padding included: a
 #: padded slot costs the same HBM read), ``rerank`` (exact f32 rows),
-#: ``exhaustive`` (full-table rows on the fallback path). The bench's
+#: ``exhaustive`` (full-table rows on the fallback path). The
 #: candidates-scanned fraction is (coarse + rerank) / (exhaustive-
 #: equivalent rows)
 _CAND_SCANNED = obs_metrics.REGISTRY.counter(
@@ -1885,8 +1885,8 @@ def _book_scan(index: MIPSIndex, b: int, coarse: int,
 
 def scan_budget(index: MIPSIndex, k: int) -> Tuple[int, int, int]:
     """(global nprobe, coarse slots scanned, rerank rows) per query at
-    the current knobs — the bench's analytic candidates-scanned
-    figure, from the same quota rule the dispatch uses."""
+    the current knobs — the analytic candidates-scanned figure, from
+    the same quota rule the dispatch uses."""
     nprobe_l, _n_cand_l, coarse, rerank = _quotas(index, k)
     return nprobe_l * index.n_shards, coarse, rerank
 

@@ -266,7 +266,7 @@ def stats() -> Dict[str, Any]:
 
 
 def sweep_now() -> int:
-    """Synchronous trigger check + rebuilds (tests, bench): same code
+    """Synchronous trigger check + rebuilds (tests): same code
     path as the daemon loop, caller's thread — works whether or not
     the background daemon is running."""
     return _DAEMON.sweep(honor_stop=False)

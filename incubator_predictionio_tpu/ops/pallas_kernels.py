@@ -563,9 +563,9 @@ def flash_attention(
 # read exactly once.
 #
 # (The verdict-suggested alternative — Gram-free CG as two thin einsums
-# per iteration — RAISES traffic at bench shapes: its per-iteration stream
+# per iteration — RAISES traffic at ML-20M shapes: its per-iteration stream
 # is 2·nnz·K vs the Gram re-read's rows·K², a ratio of 2·D̄/K ≈ 2.3× on
-# the ML-20M user side and ≈ 11.7× on the item side. Keeping the Gram but
+# the user side and ≈ 11.7× on the item side. Keeping the Gram but
 # pinning it in VMEM beats both.)
 
 

@@ -244,7 +244,6 @@ def build_both_sides(
     split_row_multiple: int = 8,
     user_degrees: "np.ndarray | None" = None,
     item_degrees: "np.ndarray | None" = None,
-    on_side=None,
 ):
     """Both training orientations (user-major and item-major) built
     concurrently → ((user_light, user_heavy), (item_light, item_heavy)).
@@ -255,25 +254,18 @@ def build_both_sides(
     sequential cost — thread spawn is noise at this scale).
 
     ``user_degrees``/``item_degrees``: optional precomputed per-row
-    histograms (see :func:`build_padded_rows`). ``on_side(side, light,
-    heavy)`` — side in {"user", "item"} — fires from the worker thread
-    the moment that side finishes, so a consumer can start the H2D
-    transfer of one side's buckets while the other side is still
-    padding (bench.py's pipelined prep→device path)."""
+    histograms (see :func:`build_padded_rows`)."""
     from concurrent.futures import ThreadPoolExecutor
 
-    def side(name, rows, cols, n_rows, degrees):
-        out = split_heavy(
+    def side(rows, cols, n_rows, degrees):
+        return split_heavy(
             build_padded_rows(rows, cols, vals, n_rows, max_width=max_width,
                               row_multiple=row_multiple, degrees=degrees),
             row_multiple=split_row_multiple)
-        if on_side is not None:
-            on_side(name, out[0], out[1])
-        return out
 
     with ThreadPoolExecutor(max_workers=2) as pool:
-        fu = pool.submit(side, "user", users, items, n_users, user_degrees)
-        fi = pool.submit(side, "item", items, users, n_items, item_degrees)
+        fu = pool.submit(side, users, items, n_users, user_degrees)
+        fi = pool.submit(side, items, users, n_items, item_degrees)
         return fu.result(), fi.result()
 
 
@@ -325,7 +317,6 @@ class StreamingPrep:
         row_multiple: int = 8,
         split_row_multiple: int = 8,
         reordered: bool = False,
-        on_side=None,
     ):
         """→ same ((user_light, user_heavy), (item_light, item_heavy))
         tuple as :func:`build_both_sides`, fed the pre-accumulated degree
@@ -343,4 +334,4 @@ class StreamingPrep:
             inter.user_idx, inter.item_idx, inter.values, n_users, n_items,
             max_width=max_width, row_multiple=row_multiple,
             split_row_multiple=split_row_multiple,
-            user_degrees=ud, item_degrees=id_, on_side=on_side)
+            user_degrees=ud, item_degrees=id_)

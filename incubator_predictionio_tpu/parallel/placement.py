@@ -178,7 +178,7 @@ class FactorPlacement:
 
     # -- bookkeeping --------------------------------------------------------
     def describe(self) -> str:
-        """e.g. "4x2" — the bench record's ``shard_mesh_shape``."""
+        """e.g. "4x2": the mesh's shape along the placement's axes."""
         return "x".join(str(self.mesh.shape[a]) for a in self.axes)
 
     def cache_key(self) -> str:

@@ -60,7 +60,7 @@ class AdminServer:
         # the self-driving freshness controller (obs/controller.py):
         # the admin process hosts its evaluation loop and exposes its
         # decision audit trail. A custom-wired instance (retrain/reload
-        # actuators, bench harnesses) can be injected; the default is
+        # actuators, a test's harness) can be injected; the default is
         # the env-wired process controller.
         if controller is None:
             from incubator_predictionio_tpu.obs.controller import (
@@ -70,7 +70,7 @@ class AdminServer:
             controller = get_controller()
         self.controller = controller
         # the self-tuning knob controller (obs/knobs.py): same hosting
-        # contract — injectable for bench harnesses, env-wired default
+        # contract — injectable by a test's harness, env-wired default
         if knobs is None:
             from incubator_predictionio_tpu.obs.knobs import (
                 get_knob_controller,

@@ -183,14 +183,6 @@ class ServerConfig:
     log_prefix: str = ""
 
 
-#: compat alias — the fixed micro-batcher grew into the continuous-
-#: batching scheduler (serving/scheduler.py): per-engine admission
-#: queues, queue-depth-adaptive pow2 batch widths, the
-#: PIO_SERVE_MAX_WAIT_MS age bound, and SLO-driven load shedding. The
-#: constructor signature is unchanged (handle_batch, max_batch,
-#: workers=…); ``max_batch`` is now the ladder CAP.
-_MicroBatcher = BatchScheduler
-
 #: retry choreography for the fire-and-forget posters (feedback events,
 #: --log-url shipping): the shared utils/http.RetryPolicy — jittered
 #: exponential backoff under a hard deadline, honoring Retry-After on a
@@ -511,8 +503,8 @@ class PredictionServer:
             self.algorithms = algorithms
             self.serving = serving
             self.models = models
-            # getattr: tests and the bench build servers via __new__
-            # with hand-injected state
+            # getattr: tests build servers via __new__ with
+            # hand-injected state
             old_overlays = getattr(self, "_speed_overlays", [])
             self._speed_overlays = overlays
         # hot model swap: the OLD overlays' vectors were solved against
@@ -654,8 +646,8 @@ class PredictionServer:
         tenant's own deploy when one is resident."""
         t0 = time.perf_counter()
         with self._lock:
-            # getattr: tests and the bench build servers via __new__
-            # with hand-injected state
+            # getattr: tests build servers via __new__ with
+            # hand-injected state
             dep = (getattr(self, "_deploys", {}).get(tenant)
                    if tenant != tenancy.DEFAULT_TENANT else None)
             if dep is not None:

@@ -1,11 +1,10 @@
 """Fleet front door — one address, health-checked routing, zero-downtime
 rolling reload.
 
-`bench_fleet`'s load generator used to spray worker processes directly:
-no single address, per-process `/reload`, and a worker joining the fleet
-paid the full XLA compile wall before it could serve (ROADMAP item 1).
-This module is the serving control plane in front of N prediction
-workers:
+Without it a client sprays worker processes directly: no single
+address, per-process `/reload`, and a worker joining the fleet pays the
+full XLA compile wall before it can serve. This module is the serving
+control plane in front of N prediction workers:
 
 - **Queue-depth-aware placement.** Each worker's score is the front
   door's own in-flight count plus the worker's last reported scheduler
@@ -45,9 +44,7 @@ workers:
   is warm (tests/fleet_worker.py), and the shared persistent XLA
   compile cache (utils/compile_cache.py, ``JAX_COMPILATION_CACHE_DIR``
   at a fleet-shared directory) turns that warmup from a compile wall into a
-  disk read — join-to-first-dispatch is seconds, measured by
-  ``bench.py bench_frontdoor`` as ``frontdoor_join_to_first_dispatch_s``
-  with the cold/warm delta recorded.
+  disk read (join-to-first-dispatch on the chip: not measured).
 
 Exported series: ``pio_frontdoor_requests_total{worker,outcome}``
 (``outcome="unauthorized"`` = accessKey rejected at the door),
@@ -164,7 +161,7 @@ class FrontDoorConfig:
 class Worker:
     """One fleet member's routing state. All mutation happens on the
     front door's event loop (handlers + probe loop share it), so no
-    lock; cross-thread readers (stats from the bench) see GIL-atomic
+    lock; cross-thread readers (``stats()`` off the loop) see GIL-atomic
     snapshots of scalars."""
 
     __slots__ = ("name", "host", "port", "state", "fails", "open_until",
@@ -782,7 +779,7 @@ class FrontDoor:
 
     def rolling_reload(self, timeout: Optional[float] = None,
                        tenant: Optional[str] = None) -> Dict[str, Any]:
-        """Synchronous wrapper for callers off the loop (bench, CLI)."""
+        """Synchronous wrapper for callers off the loop (tests, CLI)."""
         loop = self.http._loop
         if loop is None or not loop.is_running():
             raise RuntimeError("front door is not running")

@@ -57,7 +57,7 @@ names:
   evicted on an aggressor's behalf.
 
 Exported series: ``pio_serve_batch_size`` (pow2 buckets — the fused
-width distribution, the fleet bench's ``fleet_batch_p50`` source),
+width distribution),
 ``pio_serve_queue_wait_seconds``,
 ``pio_serve_shed_total{tenant,reason}`` (tenant values come from the
 bounded registry — the ``unscoped-tenant-metric`` lint contract), and
@@ -318,10 +318,8 @@ class BatchScheduler:
     key, for multi-engine hosts; a three-parameter handler —
     ``handle_batch(bodies, engine, tenant)`` — also receives the
     tenant, for multi-deploy hosts (servers/prediction_server.py routes
-    each tenant's batch to its own deploy). Construction-time signature
-    stays compatible with the old ``_MicroBatcher(handle, max_batch,
-    workers=…)``; ``max_batch`` is now the LADDER CAP the adaptive rung
-    grows toward, not the fixed fuse width.
+    each tenant's batch to its own deploy). ``max_batch`` is the LADDER
+    CAP the adaptive rung grows toward, not a fixed fuse width.
     """
 
     def __init__(

@@ -334,7 +334,7 @@ class SpeedOverlay:
     # -- the poll cycle -----------------------------------------------------
     def poll(self, max_keys: Optional[int] = None) -> Dict[str, Any]:
         """One subscriber cycle: tail read → dirty marking → batched
-        fold-in. Returns a stats dict (tests and the bench read it)."""
+        fold-in. Returns a stats dict (the tests read it)."""
         from incubator_predictionio_tpu.data.store import EventStore
 
         cfg = self.config

@@ -53,6 +53,8 @@ _UNMATCHED_ROUTE = "<unmatched>"
 
 MAX_HEADER_BYTES = 64 * 1024
 MAX_BODY_BYTES = 64 * 1024 * 1024
+#: ``HttpServer.stop`` aborts a connection still serving after this long
+STOP_GRACE_S = 5.0
 
 STATUS_TEXT = {
     200: "OK", 201: "Created", 202: "Accepted", 204: "No Content",
@@ -398,6 +400,12 @@ class HttpServer:
         self._loop: Optional[asyncio.AbstractEventLoop] = None  # pio-lint: publish-only
         self._thread: Optional[threading.Thread] = None
         self._started = threading.Event()
+        # open connections, and those of them that wait for a request's
+        # head. Loop thread only (_close and _abort_open are scheduled
+        # onto it), which the lint's thread paths cannot see.
+        self._conns: set = set()
+        self._idle: set = set()
+        self._stopping = False
 
     @classmethod
     def from_conf(cls, router: Router, host: str = "0.0.0.0",
@@ -415,8 +423,12 @@ class HttpServer:
     async def _handle_conn(
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
+        # pio-lint: disable=unguarded-shared-state
+        self._conns.add(writer)
         try:
-            while True:
+            while not self._stopping:
+                # pio-lint: disable=unguarded-shared-state
+                self._idle.add(writer)
                 try:
                     head = await reader.readuntil(b"\r\n\r\n")
                 except (asyncio.IncompleteReadError, ConnectionResetError):
@@ -426,6 +438,8 @@ class HttpServer:
                                  .encode(False))
                     await writer.drain()
                     return
+                finally:
+                    self._idle.discard(writer)
                 if len(head) > MAX_HEADER_BYTES:
                     writer.write(Response(413, {"message": "headers too large"})
                                  .encode(False))
@@ -438,6 +452,7 @@ class HttpServer:
                     await writer.drain()
                     return
                 response = await self._dispatch(request)
+                keep_alive = keep_alive and not self._stopping
                 writer.write(response.encode(keep_alive))
                 await writer.drain()
                 if not keep_alive:
@@ -445,6 +460,7 @@ class HttpServer:
         except Exception:
             logger.exception("connection handler error")
         finally:
+            self._conns.discard(writer)
             try:
                 writer.close()
                 await writer.wait_closed()
@@ -655,12 +671,28 @@ class HttpServer:
         return self.port
 
     def stop(self) -> None:
-        loop, server = self._loop, self._server
-        if loop is not None and server is not None:
+        """End the server, not only its listener: idle keep-alive
+        connections close at once, one with a request in flight after
+        its response (``Connection: close``) or ``STOP_GRACE_S`` later.
+        asyncio's ``serve_forever`` (`pio deploy`) returns only when
+        every connection has gone."""
+        loop = self._loop
+        if loop is not None and self._server is not None:
             try:
-                loop.call_soon_threadsafe(server.close)
+                loop.call_soon_threadsafe(self._close)
             except RuntimeError:
                 pass  # loop already closed (server stopped itself)
+
+    def _close(self) -> None:
+        self._stopping = True
+        self._server.close()
+        for writer in list(self._idle):
+            writer.close()
+        self._loop.call_later(STOP_GRACE_S, self._abort_open)
+
+    def _abort_open(self) -> None:
+        for writer in list(self._conns):
+            writer.transport.abort()
 
 
 async def sync(fn: Callable[..., Any], *args: Any) -> Any:

@@ -1,5 +1,6 @@
 """Seeded planted-factor catalogue generator — the shared fixture of the
-two-stage MIPS serving path (tests AND bench legs import it).
+two-stage MIPS serving path (``ops/mips.recall_probe`` and the tests
+import it).
 
 ML-20M tops out at ~27k items, far too small to measure an
 approximate-MIPS win; real embedding catalogues are 10-100× larger. This
@@ -14,12 +15,11 @@ to the cluster radius) is ~75% noise at rank 64 and NO index structure
 can beat a linear scan on it — which is a statement about the fixture,
 not about serving. Here ``noise`` is the RELATIVE within-cluster radius
 (noise vector norm over center norm), matching the spectral decay of
-trained MF factors, and the recall gate (tests/test_mips.py,
-``bench_mips``) is honest because the exhaustive oracle runs on the
-same table.
+trained MF factors, and the recall gate (tests/test_mips.py) is honest
+because the exhaustive oracle runs on the same table.
 
-Everything is a pure function of the seed — the determinism tests and
-the bench compare runs byte-for-byte.
+Everything is a pure function of the seed — the determinism tests
+compare runs byte-for-byte.
 """
 
 from __future__ import annotations

@@ -74,7 +74,7 @@ def main() -> int:
     u_tree, i_tree = als._buckets_tree(ul), als._buckets_tree(il)
     u_hv, i_hv = als._heavy_tree(uh), als._heavy_tree(ih)
 
-    # analytic FLOPs (bench.py convention, bf16 CG budget)
+    # analytic FLOPs (the convention of ops/als.train_flops, bf16 CG budget)
     k = float(rank)
     iters_cg = min(als._CG_ITERS_BF16, als._CG_ITERS)
     per_sweep = (2 * (2.0 * nnz * k * k * 2.0) + 2 * (2.0 * nnz * k)

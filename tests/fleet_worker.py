@@ -1,7 +1,7 @@
-"""Worker program for the fleet tests and the fleet serving bench.
+"""Worker program for the fleet tests.
 
-Run as a REAL separate process by tests/test_federation.py and by
-``bench.py bench_fleet`` / ``bench_frontdoor``:
+Run as a REAL separate process by tests/test_federation.py and
+tests/test_recorder.py (tests/test_frontdoor.py imports its chaos hooks):
 
 - ``--mode metrics``: an HttpServer exposing ``GET /metrics`` from its
   own process registry, with a planted query-latency histogram and
@@ -16,10 +16,10 @@ Run as a REAL separate process by tests/test_federation.py and by
   (random factors, synthetic catalog), serving ``/queries.json``
   through the continuous-batching scheduler (serving/scheduler.py) with
   the pow2 ladder pre-warmed before the port is announced — one worker
-  of the ``bench_fleet`` / ``bench_frontdoor`` legs. ``/metrics`` on
-  the same port exposes ``pio_serve_batch_size`` /
-  ``pio_serve_shed_total`` / ``pio_serve_compile_cache_size`` for the
-  bench's scrapes, and ``POST /reload`` hot-swaps to a freshly planted
+  of a fleet behind ``serving.FrontDoor``. ``/metrics`` on the same
+  port exposes ``pio_serve_batch_size`` / ``pio_serve_shed_total`` /
+  ``pio_serve_compile_cache_size``, and ``POST /reload`` hot-swaps to a
+  freshly planted
   model through the real warm-before-swap route (what the front door's
   rolling reload drives).
 
@@ -27,8 +27,7 @@ Run as a REAL separate process by tests/test_federation.py and by
 jax work, at the FLEET-SHARED directory utils/compile_cache.py resolves
 (``JAX_COMPILATION_CACHE_DIR`` if the parent exports one, else the
 in-checkout default), so a joining worker pre-warms its pow2 ladder from
-disk instead of paying the cold compile wall — the elasticity story
-bench_frontdoor measures.
+disk instead of paying the cold compile wall.
 
 ``--chaos SPEC`` arms fault injection (comma-separated; serve mode):
 
@@ -42,8 +41,8 @@ bench_frontdoor measures.
   connections refused; already-open keep-alives keep serving)
 
 Prints ``PORT <n> WARM_S <seconds>`` on stdout once bound (serve mode:
-once WARM; WARM_S is the ladder warmup wall — the cold/warm
-compile-cache delta the bench records), then serves until stdin closes
+once WARM; WARM_S is the ladder warmup wall, cold or from the compile
+cache), then serves until stdin closes
 (the parent owns the lifetime; no signals needed).
 """
 
@@ -151,8 +150,8 @@ def _serve_worker(args) -> tuple:
     algo = ALSAlgorithm(ALSAlgorithmParams(rank=rank))
     now = now_utc()
     server = PredictionServer.__new__(PredictionServer)
-    # direct state injection (the bench_serving pattern): this worker
-    # measures the serving plane, not checkpoint restore
+    # direct state injection: this worker exercises the serving plane,
+    # not checkpoint restore
     server.engine = None
     server.config = ServerConfig(ip="127.0.0.1", port=0,
                                  micro_batch=args.max_batch)
@@ -225,7 +224,7 @@ def _serve_worker(args) -> tuple:
         # SLO signal by tenant)
         p99_fn=lambda tenant: ps_mod._QUERY_LATENCY.labels(
             tenant=tenancy.get_registry().label(tenant)).quantile(0.99))
-    # PIO_TENANTS (bench_tenants sets it in the worker env) → weighted-
+    # PIO_TENANTS (set in the worker's env by its parent) → weighted-
     # fair weights + admission quotas pushed into the scheduler, same
     # seam the real server syncs after construction and reloads
     server._sync_tenant_policy()
@@ -258,7 +257,8 @@ def _serve_worker(args) -> tuple:
             # tenant-scoped reload: swap ONLY this tenant's co-resident
             # deploy — the shared/default deploy (and every other
             # tenant riding it) keeps serving the old model untouched,
-            # which is exactly what bench_tenants' reload stage proves
+            # (tests/test_tenancy.py's tenant-scoped reload holds the
+            # server's side of this)
             if tenancy.get_registry().get(tenant) is None:
                 from incubator_predictionio_tpu.utils.http import (
                     HttpError,

@@ -68,7 +68,7 @@ def test_als_mixed_bf16_schedule_recovers_planted_rank():
     """bf16 early sweeps + f32 polish land on the same fixed point as the
     all-f32 run: ALS re-solves every row from scratch each half-sweep, so
     low-precision sweeps only change the polish's starting point. Guards
-    the bench's mixed schedule (bench.py PIO_BENCH_BF16_SWEEPS)."""
+    the mixed schedule (``bf16_sweeps``)."""
     users, items, ratings = synthetic_ratings(
         n_users=80, n_items=50, rank=4, density=0.4, seed=3)
     f32, _ = als_train(users, items, ratings, 80, 50, rank=8,

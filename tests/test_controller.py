@@ -143,6 +143,38 @@ def test_staleness_headroom_projection_acts_before_the_bound():
     assert d["projection"]["projectionS"] == pytest.approx(5.0)
 
 
+def test_closed_loop_holds_the_bound_with_no_false_trigger():
+    """The loop alone, no human retrain: staleness grows a second a
+    second and only the controller's reload takes it back to zero. It
+    keeps the fleet under the bound and never acts while the model is
+    still comfortably fresh (under half the bound)."""
+    bound = 100.0
+    clock = FakeClock(1000.0)
+    eng, gauge = planted_engine(clock, threshold=bound)
+    refreshed = [clock()]
+
+    def reload():
+        refreshed.append(clock())
+        return {"reloaded": 2}
+
+    ctl, calls = make_controller(clock, eng, horizon=10.0, breach_evals=2,
+                                 cooldown=5.0, reload_fn=reload)
+    peak, actions = 0.0, []
+    for _ in range(500):
+        clock.advance(1.0)
+        staleness = clock() - refreshed[-1]
+        peak = max(peak, staleness)
+        gauge.set(staleness)
+        d = ctl.evaluate_once()
+        if (d.get("outcome") or {}).get("actuated"):
+            actions.append(d)
+    assert len(actions) >= 4 and calls["retrain"] == len(actions)
+    assert peak <= bound
+    assert all(d["inputs"]["stalenessMaxS"] >= 0.5 * bound
+               for d in actions)
+    assert all(d["trigger"] == "staleness_projection" for d in actions)
+
+
 def test_burn_breach_triggers():
     clock = FakeClock(100.0)
     eng, gauge = planted_engine(clock, threshold=100.0)

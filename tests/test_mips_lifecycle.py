@@ -341,17 +341,20 @@ def test_daemon_triggers_and_sweep(planted, mips_on, monkeypatch):
 
 
 def test_daemon_lifecycle_is_refcounted():
-    assert not mips_daemon.running()
-    mips_daemon.acquire()
-    mips_daemon.acquire()
+    # a daemon of the test's own: the process's one may still be held by
+    # a server that an earlier test file on this worker left deployed
+    daemon = mips_daemon._RebuildDaemon()
+    assert not daemon.running()
+    daemon.acquire()
+    daemon.acquire()
     try:
-        assert mips_daemon.running()
-        mips_daemon.release()
-        assert mips_daemon.running()              # one holder left
+        assert daemon.running()
+        daemon.release()
+        assert daemon.running()                   # one holder left
     finally:
-        mips_daemon.release()
-    assert not mips_daemon.running()
-    assert mips_daemon.stats()["running"] is False
+        daemon.release()
+    assert not daemon.running()
+    assert daemon.stats()["running"] is False
 
 
 # ---------------------------------------------------------------------------

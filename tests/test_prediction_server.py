@@ -50,8 +50,11 @@ class RewritingBlocker(EngineServerPlugin):
 
 
 @pytest.fixture
-def stack():
+def stack(monkeypatch):
     """memory storage + trained engine + event server + prediction server."""
+    # no test of this file tests shedding: off, as the benchmark's cells
+    # run, or a loaded box's shed projection answers a query with a 503
+    monkeypatch.setenv("PIO_SERVE_SHED", "0")
     Storage.configure({
         "PIO_STORAGE_SOURCES_MEM_TYPE": "memory",
         "PIO_STORAGE_REPOSITORIES_METADATA_NAME": "m",
@@ -355,6 +358,84 @@ def test_bind_fails_after_retries_exhausted(stack):
         sock.close()
 
 
+def _keep_alive_conn(port):
+    """A client that has made one request and holds its connection."""
+    import http.client
+
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=5)
+    conn.request("POST", "/stop", body=b"")
+    resp = conn.getresponse()
+    resp.read()
+    assert not resp.will_close
+    return conn, resp.status
+
+
+def test_stop_closes_idle_keep_alive_connections():
+    """stop() ends the server, not only its listener: an idle keep-alive
+    connection is closed at once and the serving thread ends."""
+    srv, _hits = _mini_server()
+    port = srv.start_background()
+    conn, status = _keep_alive_conn(port)
+    try:
+        assert status == 404
+        srv.stop()
+        conn.sock.settimeout(1.0)
+        assert conn.sock.recv(1) == b""  # closed by the server
+        srv._thread.join(1.0)
+        assert not srv._thread.is_alive()
+    finally:
+        conn.close()
+
+
+def test_stop_lets_a_request_in_flight_finish_then_closes():
+    import http.client
+    import threading
+
+    from incubator_predictionio_tpu.utils import http as pio_http
+
+    r = pio_http.Router()
+    entered, release = threading.Event(), threading.Event()
+
+    @r.get("/slow")
+    def slow(request):
+        entered.set()
+        release.wait(10)
+        return pio_http.Response(200, {"done": True})
+
+    srv = pio_http.HttpServer(r, "127.0.0.1", 0)
+    port = srv.start_background()
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=5)
+    try:
+        conn.request("GET", "/slow")
+        assert entered.wait(5)
+        srv.stop()
+        srv._thread.join(0.3)
+        assert srv._thread.is_alive()  # the request is still being served
+        release.set()
+        resp = conn.getresponse()
+        assert resp.status == 200 and json.loads(resp.read()) == {"done": True}
+        assert resp.will_close  # Connection: close
+        srv._thread.join(2.0)
+        assert not srv._thread.is_alive()
+    finally:
+        release.set()
+        conn.close()
+
+
+def test_deploy_returns_after_stop_with_a_client_still_connected(stack):
+    """`pio deploy` is `serve_forever()`: it must return after /stop
+    though a client still holds a keep-alive connection."""
+    ps, port, _es, _esp = stack
+    conn, status = _keep_alive_conn(port)  # unauthenticated: 401, kept alive
+    try:
+        assert status == 401
+        assert call(port, "POST", "/stop?accessKey=sekrit")[0] == 200
+        ps.http._thread.join(5.0)
+        assert not ps.http._thread.is_alive()
+    finally:
+        conn.close()
+
+
 def test_undeploy_before_deploy_replaces_stale_server(stack):
     """Deploying onto an address with a live engine server stops the old
     one first (undeploy-before-deploy, CreateServer.scala:283-308)."""
@@ -568,7 +649,7 @@ def test_fast_path_served_through_http():
     )
     from incubator_predictionio_tpu.servers.prediction_server import (
         _AsyncPoster,
-        _MicroBatcher,
+        BatchScheduler,
     )
     from incubator_predictionio_tpu.utils import json_codec
     from incubator_predictionio_tpu.utils.http import HttpServer
@@ -609,7 +690,7 @@ def test_fast_path_served_through_http():
     srv.max_batch_served = 0
     srv._conf_server_key = None
     srv.http = HttpServer(srv._build_router(), "127.0.0.1", 0)
-    srv._batcher = _MicroBatcher(srv._handle_batch, srv.config.micro_batch)
+    srv._batcher = BatchScheduler(srv._handle_batch, srv.config.micro_batch)
     srv._feedback_poster = _AsyncPoster("feedback")
     srv._log_poster = _AsyncPoster("log", workers=1)
     port = srv.http.start_background()

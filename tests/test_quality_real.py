@@ -5,56 +5,58 @@ interaction data in this egress-free environment — read at run time from
 the read-only reference tree (never copied into the repo; provenance:
 /root/reference/examples/experimental/data/movielens.txt, the file the
 reference's own movielens tutorials consume). Skips when the reference
-tree is not mounted. Loader, split, and hyperparameters are bench.py's
-own (shared code, not a copy), so the pinned bound always guards the
-exact configuration the bench record reports."""
+tree is not mounted."""
 
 import os
 
 import numpy as np
 import pytest
 
-import bench
+#: user::item::rating, 1.5k real ratings: 30 users × 100 items, std 1.19
+MOVIELENS_SAMPLE = "/root/reference/examples/experimental/data/movielens.txt"
+#: 1.2k training ratings cannot support a wide rank (it would fit the
+#: noise): rank 8, λ=0.1 measured best of a small grid on this sample
+MOVIELENS_RANK = 8
+MOVIELENS_L2 = 0.1
+#: measured 1.076/1.058/1.024 across seeds 0..2 (10 sweeps, 80/20
+#: split). 1.20 is ~11% headroom over the worst seed and below the 1.31
+#: a mis-regularized run measures.
+MOVIELENS_RMSE_BOUND = 1.20
 
 pytestmark = pytest.mark.skipif(
-    not os.path.exists(bench.MOVIELENS_SAMPLE),
+    not os.path.exists(MOVIELENS_SAMPLE),
     reason="reference movielens sample not available")
 
 
-def test_movielens_stage_clears_pinned_bound():
-    """The bench stage itself (same loader, split seed, rank/λ) must keep
-    beating the pinned bound on real ratings (measured 1.024-1.076
-    across seeds; a mis-regularized run measures >=1.31)."""
-    out = bench.bench_movielens_quality()
-    assert set(out) == {"movielens_rmse", "movielens_rmse_bound"}
-    assert out["movielens_rmse"] is not None
-    assert out["movielens_rmse"] <= out["movielens_rmse_bound"], out
+def load_movielens_sample():
+    """→ (users, items, vals, n_users, n_items) from the sample file."""
+    with open(MOVIELENS_SAMPLE) as f:
+        rows = [line.strip().split("::") for line in f if line.strip()]
+    users = np.asarray([int(r[0]) for r in rows], np.int32)
+    items = np.asarray([int(r[1]) for r in rows], np.int32)
+    vals = np.asarray([float(r[2]) for r in rows], np.float32)
+    # dense reindex (ids in the file are sparse)
+    uu, users = np.unique(users, return_inverse=True)
+    ii, items = np.unique(items, return_inverse=True)
+    return (users.astype(np.int32), items.astype(np.int32), vals,
+            len(uu), len(ii))
 
 
-def test_movielens_model_beats_constant_predictor():
-    """...and the model is a real model: better than predicting the
-    train-mean on the same 80/20 split the stage uses."""
+def test_movielens_model_keeps_its_heldout_rmse():
+    """The solver stays healthy on real human ratings: under the pinned
+    bound, and a real model — better than predicting the train-mean on
+    the same 80/20 split."""
     from incubator_predictionio_tpu.ops import als
 
-    users, items, vals, n_u, n_i = bench.load_movielens_sample()
+    users, items, vals, n_u, n_i = load_movielens_sample()
     rng = np.random.default_rng(0)
     perm = rng.permutation(len(vals))
     cut = int(0.8 * len(vals))
     tr, te = perm[:cut], perm[cut:]
     state, _ = als.als_train(
         users[tr], items[tr], vals[tr], n_u, n_i,
-        rank=bench.MOVIELENS_RANK, iterations=10, l2=bench.MOVIELENS_L2,
-        seed=0)
+        rank=MOVIELENS_RANK, iterations=10, l2=MOVIELENS_L2, seed=0)
     rmse = als.rmse(state, users[te], items[te], vals[te])
     const = float(np.sqrt(np.mean((vals[te] - vals[tr].mean()) ** 2)))
     assert rmse < const, (rmse, const)
-
-
-def test_unusable_sample_skips_not_crashes(monkeypatch, tmp_path):
-    """A malformed sample (wrong format via PIO_BENCH_MOVIELENS) must
-    yield the null record keys, never crash the orchestrator."""
-    bad = tmp_path / "u.data"
-    bad.write_text("1\t2\t3\t881250949\n")  # ML-100K tab format
-    monkeypatch.setattr(bench, "MOVIELENS_SAMPLE", str(bad))
-    out = bench.bench_movielens_quality()
-    assert out == {"movielens_rmse": None, "movielens_rmse_bound": None}
+    assert rmse <= MOVIELENS_RMSE_BOUND, rmse

@@ -128,6 +128,57 @@ def test_follower_catches_up_and_drains_new_writes(
         follower.stop()
 
 
+def test_follower_lag_is_bounded_under_sustained_writes(
+        leader, tmp_path, monkeypatch):
+    """A writer keeps appending while the follower tails: its lag never
+    exceeds what has been written, append-only growth never makes it
+    start a shard over, and it drains to zero with read parity."""
+    import threading
+
+    from incubator_predictionio_tpu.obs import metrics as obs_metrics
+
+    _server, revents, lport, _dir, _client = leader
+    follower, fevents, fc = _start_follower(tmp_path, monkeypatch, lport)
+    tail = follower.replication
+    resets = []
+    reset = tail.local.replication_reset
+    written = [0]
+
+    def writer():
+        for b in range(12):
+            revents.insert_batch(
+                [_ev(f"u{b * 20 + i}", b * 20 + i, target=f"i{i % 3}")
+                 for i in range(20)], 1)
+            written[0] += 20
+
+    t = threading.Thread(target=writer)
+    try:
+        assert tail.wait_caught_up(timeout_s=30)  # its first sync is done
+        monkeypatch.setattr(
+            tail.local, "replication_reset",
+            lambda *a, **kw: resets.append(kw) or reset(*a, **kw))
+        t.start()
+        lags = []
+        while t.is_alive():
+            lags.append((tail._lag_total(1), written[0] + 20))
+            t.join(0.01)
+        assert lags and all(0 <= lag <= cap for lag, cap in lags)
+        assert tail.wait_caught_up(timeout_s=30)
+        assert tail._lag_total(1) == 0
+        assert resets == []
+        _parity(fevents.scan_interactions(**SCAN_KW),
+                revents.scan_interactions(**SCAN_KW))
+        assert len(fevents.scan_interactions(**SCAN_KW)) == 240
+        gauge = obs_metrics.REGISTRY.get("pio_replication_lag_events")
+        tail._sync_app(1)
+        assert [gauge.labels(shard=str(k)).value for k in (0, 1)] == [0, 0]
+    finally:
+        if t.ident is not None:
+            t.join(30)
+        fc.close()
+        follower.stop()
+
+
 def test_follower_resyncs_after_leader_restart(
         leader, tmp_path, monkeypatch):
     """Kill the leader mid-replication, bring it back ON THE SAME PORT
@@ -141,6 +192,10 @@ def test_follower_resyncs_after_leader_restart(
     try:
         assert follower.replication.wait_caught_up(timeout_s=30)
         server.stop()
+        # the old leader is gone, listener and keep-alive connections,
+        # before the new one binds its port
+        server.http._thread.join(10)
+        assert not server.http._thread.is_alive()
         cfg2 = base.StorageClientConfig(
             parallel=False, test=True, properties={"PATH": str(ldir)})
         client2 = cpplog.StorageClient(cfg2)

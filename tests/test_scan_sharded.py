@@ -318,7 +318,7 @@ def test_concurrent_cache_stages_use_distinct_tmp_files(tmp_path):
 
 
 def test_scan_stats_report_lock_narrowing(events, monkeypatch):
-    """The stats channel the bench records: shard walls and the native
+    """The scan's stats channel: shard walls and the native
     lock-held wall must be present and the lock-held share must be far
     below the scan wall at any real size (here just sanity > 0 keys)."""
     _build_random_log(events, np.random.default_rng(8), n=200,

@@ -6,7 +6,7 @@ decision):
 
 - ladder-rung growth/collapse (``plan_dispatch`` — the pure rule)
 - the PIO_SERVE_MAX_WAIT_MS age bound: a query is never held past it
-  (the _MicroBatcher starvation regression)
+  (the fixed micro-batcher's starvation regression)
 - per-engine queue isolation: batches never mix engines, rungs adapt
   independently
 - SLO-projected load shedding: overload sheds 503 + Retry-After,

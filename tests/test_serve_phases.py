@@ -71,6 +71,22 @@ class _SpyChildren(dict):
         return self[name]
 
 
+class _StageClock:
+    """Stands in for the ``time`` module inside obs/trace: ``stage``
+    reads a clock that only the test moves, on however loaded a box."""
+
+    def __init__(self):
+        self.now = 0.0
+        self.was_read = threading.Event()
+
+    def perf_counter(self):
+        self.was_read.set()
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
 def test_geometric_buckets_step_at_most_one_and_a_half():
     for lo, hi in ((50e-6, 1.0), (0.25e-3, 1.0)):
         b = obs_metrics.geometric_buckets(lo, hi)
@@ -85,24 +101,26 @@ def test_phases_tile_the_dispatcher_threads_life(monkeypatch):
     n0 = batches.count
     seen = []
 
+    clock = _StageClock()
+    monkeypatch.setattr(obs_trace, "time", clock)
+
     def handler(bodies):
         seen.append(len(bodies))
-        time.sleep(0.001)
+        clock.now += 0.001
         return bodies
 
     # cap 1: one query a dispatch, so 64 queued queries are 64
     # dispatches with the queue never empty between them
     s = BatchScheduler(handler, 1, shed=False, wait_bound_s=0.0)
-    t_start = time.perf_counter()       # its thread has just started
     thread = s._threads[0]
-    time.sleep(0.1)                     # the queue is empty: wait grows
-    t_busy = time.perf_counter()
+    assert clock.was_read.wait(5)       # the thread is in its first wait
+    clock.now += 0.1                    # the queue is empty: wait grows
     futs = [s.submit(i) for i in range(64)]
     assert [f.result(10) for f in futs] == list(range(64))
-    busy_s = time.perf_counter() - t_busy
+    busy_s = clock.now - 0.1
     s.stop()
     thread.join(5)
-    life_s = time.perf_counter() - t_start
+    life_s = clock.now                  # its thread started at 0.0
     assert not thread.is_alive()
 
     assert len(seen) == 64
@@ -117,10 +135,10 @@ def test_phases_tile_the_dispatcher_threads_life(monkeypatch):
     assert sum(by_phase["other"]) >= 64 * 0.001
     # the identity: every second of the thread's life is in one phase
     total = sum(sec for _p, sec in spy.booked)
-    assert total == pytest.approx(life_s, rel=0.02)
+    assert total == pytest.approx(life_s, rel=1e-9)
     # wait grew while the queue was empty ...
     waits = by_phase["wait"]
-    assert waits[0] >= 0.09
+    assert waits[0] == pytest.approx(0.1)
     # ... and not while it was not: the 63 waits between two dispatches
     # of the backlog hold a pick and a pop each
     assert sum(waits[1:64]) < 0.05 * busy_s

@@ -601,8 +601,7 @@ def test_fused_train_books_under_its_own_op_label(monkeypatch):
     """Kernel-path training attributes under op="als_fused", the XLA
     assembly under op="als_train" — separate trajectories in /metrics —
     while both book the SAME als.train_flops formula, so
-    pio_mfu{phase="train"} stays comparable across the split (the
-    bench's obs_mfu_train cross-check relies on it)."""
+    pio_mfu{phase="train"} stays comparable across the split."""
     from incubator_predictionio_tpu.ops import als
 
     monkeypatch.setenv("PIO_PROFILE", "1")

@@ -348,6 +348,45 @@ def test_overlay_fold_in_and_per_user_invalidation(mem_store):
     assert np.allclose(vec2, ref2, atol=1e-3)
 
 
+def test_overlay_serves_hits_under_concurrent_ingest(mem_store):
+    """A writer appends cold users' events while this thread polls the
+    overlay and looks every user ingested so far up: users not yet
+    folded in miss, folded ones hit, and once the writer is done and
+    the dirty set drained every ingested user is served."""
+    import threading
+
+    app = mem_store
+    rng = np.random.default_rng(23)
+    other = rng.normal(0, 0.3, (20, 8)).astype(np.float32)
+    idx = {f"i{k}": k for k in range(20)}
+    ov = _overlay(app, other, idx, FakeClock(), ttl_s=600.0)
+    ingested = []                       # cold user ids, in ingest order
+    items = rng.integers(0, 20, (24, 3))
+
+    def writer():
+        for j, row in enumerate(items):
+            for i in row:
+                _rate(app, f"cold{j}", f"i{i}", 4.0)
+            ingested.append(f"cold{j}")
+
+    t = threading.Thread(target=writer)
+    t.start()
+    max_lag = 0
+    while t.is_alive() or ov.stats()["dirty"] or ov.poll()["tail_rows"]:
+        s = ov.poll()
+        max_lag = max(max_lag, int(s.get("lag", 0)))
+        for uid in list(ingested):
+            ov.lookup(uid)
+        t.join(0.001)
+    assert not t.is_alive() and len(ingested) == 24
+    assert all(ov.lookup(uid) is not None for uid in ingested)
+    st = ov.stats()
+    assert st["foldins"] >= 1 and st["dirty"] == 0
+    looked = st["hits"] + st["misses"]
+    assert st["hits"] >= 24 and 0.0 < st["hits"] / looked <= 1.0
+    assert max_lag >= 0 and st["cursorLagEvents"] == 0
+
+
 def test_overlay_ttl_and_wholesale_invalidation(mem_store):
     app = mem_store
     other = np.eye(8, dtype=np.float32)[: 8]

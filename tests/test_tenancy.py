@@ -6,7 +6,7 @@ metric-safe label gateway), the scheduler's tenant isolation planes
 the prediction server's access-key query path + tenant-scoped reload,
 the per-tenant SLO specs, and the capacity report's per-tenant sizing
 helpers — the PR-20 acceptance surface that is unit-testable without
-the bench fleet (bench.py bench_tenants covers the end-to-end bars).
+a fleet of worker processes.
 """
 
 import base64

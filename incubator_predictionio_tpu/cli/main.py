@@ -631,6 +631,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     except CommandError as e:
         print(f"Error: {e}", file=sys.stderr)
         return 1
+    finally:
+        # a server verb's last span lines (its /stop among them) are
+        # written before the verb returns: whoever called it may close
+        # standard error next
+        from incubator_predictionio_tpu.obs.trace import flush_span_log
+
+        flush_span_log()
 
 
 if __name__ == "__main__":

@@ -8,9 +8,13 @@ of the servers gets a trace ID — accepted from an incoming
 - echoed back on the response in the same header,
 - installed in a contextvar for the duration of the handler (the HTTP
   layer copies the context into the executor for sync handlers), and
-- emitted in one structured JSON span line per request on the
+- emitted in one structured JSON span line per request, gated on the
   ``pio.trace`` logger (level INFO; silence it with
-  ``logging.getLogger("pio.trace").setLevel(logging.WARNING)``).
+  ``logging.getLogger("pio.trace").setLevel(logging.WARNING)``). Under
+  the CLI's server verbs the line is one formatted string appended to
+  the span sink (:func:`enable_span_logging`), which a thread of its
+  own writes to standard error a batch at a time; any handler that can
+  be reached from ``pio.trace`` receives it through ``logging`` too.
 
 A client that stamps its POST /events.json and POST /queries.json with
 the same trace ID can therefore join the ingest span, the serving span
@@ -27,12 +31,15 @@ being taken, and ``pio_serve_phase_seconds_total{phase}`` always.
 
 from __future__ import annotations
 
+import atexit
 import collections
 import contextvars
 import gc
 import json
 import logging
+import math
 import os
+import queue
 import random
 import re
 import secrets
@@ -140,24 +147,128 @@ def client_headers() -> dict:
     return out
 
 
+#: how often the span sink writes what has gathered: the longest a line
+#: waits for its write, and the most a hard kill can lose
+SPAN_WRITE_PERIOD_S = 0.05
+#: lines the sink holds between two writes; past it a line is dropped
+#: and counted (a stream that blocks must not grow the process)
+SPAN_BUFFER_LINES = 65536
+
+_SPAN_LINES = obs_metrics.REGISTRY.counter(
+    "pio_trace_span_lines_total",
+    "span lines the span sink wrote to its stream")
+_SPAN_WRITES = obs_metrics.REGISTRY.counter(
+    "pio_trace_span_writes_total",
+    "writes the span sink made, each carrying every line that gathered "
+    "in one period (lines over writes: how far the batching engages)")
+_SPAN_DROPPED = obs_metrics.REGISTRY.counter(
+    "pio_trace_span_lines_dropped_total",
+    "span lines that never reached the stream: the buffer was full, or "
+    "the stream was closed or refused the write")
+
+
+class _SpanSink:
+    """Where the CLI's span lines go. ``put`` is an append and nothing
+    else, from whatever thread ends a request; a daemon thread of the
+    sink's own joins what has gathered and hands it to the stream in one
+    ``write`` a period, so a stream that blocks (a full pipe) blocks that
+    thread and never an event loop or a dispatcher. The counters are
+    booked by the writer, a batch at a time."""
+
+    def __init__(self, stream: Any) -> None:
+        self._stream = stream
+        # put to by any thread, taken from only under `_write_lock`
+        self._lines: "queue.SimpleQueue[str]" = queue.SimpleQueue()
+        self._overflow = 0
+        self._overflow_lock = threading.Lock()
+        self._write_lock = threading.Lock()
+        self._wake = threading.Event()
+        self._closed = False
+        self._thread = threading.Thread(
+            target=self._run, name="pio-span-writer", daemon=True)
+        self._thread.start()
+
+    def put(self, line: str) -> None:
+        if self._lines.qsize() < SPAN_BUFFER_LINES:
+            self._lines.put(line)
+        else:
+            with self._overflow_lock:
+                self._overflow += 1
+
+    def _run(self) -> None:
+        while not self._closed:
+            self._wake.wait(SPAN_WRITE_PERIOD_S)
+            self._wake.clear()
+            self.flush()
+
+    def flush(self) -> None:
+        """Write what has gathered, on the calling thread. A stream
+        closed under the sink (the process that owns it closed its log)
+        is swallowed: nothing may print where span lines were due."""
+        with self._write_lock:
+            lines = self._lines
+            batch = [lines.get_nowait() for _ in range(lines.qsize())]
+            with self._overflow_lock:
+                dropped, self._overflow = self._overflow, 0
+            if batch:
+                try:
+                    self._stream.write("\n".join(batch) + "\n")
+                    self._stream.flush()
+                except (OSError, ValueError):
+                    dropped += len(batch)
+                else:
+                    _SPAN_LINES.inc(len(batch))
+                    _SPAN_WRITES.inc()
+            if dropped:
+                _SPAN_DROPPED.inc(dropped)
+
+    def close(self) -> None:
+        """End the writer and write what is left (``atexit``: no daemon
+        thread may be inside the stream when the interpreter goes)."""
+        self._closed = True
+        self._wake.set()
+        self._thread.join()
+        self.flush()
+
+
+#: the process's span sink, once a CLI server verb has asked for one
+_sink: Optional[_SpanSink] = None
+
+
 def enable_span_logging() -> None:
-    """Give the span logger a real sink: one bare-JSON line per request
-    on stderr. The CLI server verbs call this so `pio eventserver` /
-    `pio deploy` emit spans out of the box; library embedders configure
-    logging themselves and never pay for it (an unconfigured logger
-    fails the ``isEnabledFor`` gate). ``PIO_TRACE_LOG=off`` disables.
-    Idempotent; propagation stays on so pytest caplog and operator root
-    handlers keep seeing the records."""
+    """Give the span lines a real sink: one bare-JSON line per request
+    on stderr (as it stands at this call), written by the sink's own
+    thread every ``SPAN_WRITE_PERIOD_S`` and at an orderly stop. The CLI
+    server verbs call this so `pio eventserver` / `pio deploy` emit
+    spans out of the box; library embedders configure logging themselves
+    and never pay for it (an unconfigured logger fails the
+    ``isEnabledFor`` gate). ``PIO_TRACE_LOG=off`` disables. Idempotent;
+    the sink is no ``logging`` handler, so pytest caplog and operator
+    handlers on ``pio.trace`` or above keep seeing the records."""
+    global _sink
     if os.environ.get("PIO_TRACE_LOG", "").lower() in (
             "off", "0", "false", "disable"):
         return
-    if any(isinstance(h, logging.StreamHandler)
-           for h in span_logger.handlers):
+    if _sink is not None:
         return
-    handler = logging.StreamHandler()
-    handler.setFormatter(logging.Formatter("%(message)s"))
-    span_logger.addHandler(handler)
+    _sink = _SpanSink(sys.stderr)
+    atexit.register(_sink.close)
     span_logger.setLevel(logging.INFO)
+
+
+def flush_span_log(wait: bool = True) -> None:
+    """The lines the span sink still holds go to its stream: on the
+    calling thread (a verb's exit: the caller may close the stream
+    next), or with ``wait=False`` on the sink's writer, woken now
+    (``HttpServer.stop()``, which may run on an event loop). Nothing to
+    do in a process without the sink."""
+    sink = _sink
+    if sink is None:
+        return
+    if wait:
+        sink.flush()
+    else:
+        sink._wake.set()
 
 
 #: last parsed PIO_TRACE_SAMPLE value, keyed by the raw env string so a
@@ -187,12 +298,60 @@ def sample_rate() -> float:
 def span_sampled() -> bool:
     """Coin flip for THIS request's span line. Sampled-out requests
     still carry (and echo) their trace IDs — sampling drops only the
-    JSON log line, which at bench QPS is the per-request hot-path cost;
-    the propagation contract is unconditional."""
+    JSON log line (a format and an append per request, a share of one
+    write on the span sink's thread); the propagation contract is
+    unconditional."""
     rate = sample_rate()
     if rate >= 1.0:
         return True
     return rate > 0.0 and random.random() < rate
+
+
+def _json(value: Any) -> str:
+    """``value`` as ``json.dumps`` writes it: a string that holds no
+    byte JSON escapes is quoted in place, an int or a finite float is
+    its ``repr``, anything else goes through ``json.dumps``."""
+    kind = type(value)
+    if kind is str:
+        if (value.isascii() and value.isprintable() and '"' not in value
+                and "\\" not in value):
+            return f'"{value}"'
+    elif kind is int or (kind is float and math.isfinite(value)):
+        return repr(value)
+    return json.dumps(value, separators=(",", ":"))
+
+
+#: the keys the two kinds of line set themselves
+_FIXED_FIELDS = frozenset((
+    "span", "server", "method", "route", "status", "ts", "durationMs",
+    "traceId", "spanId", "parentSpanId"))
+
+
+def _emit(head: str, extra: Dict[str, Any]) -> None:
+    """Close the span line ``head`` with ``extra`` and send it: to the
+    span sink if the CLI made one, and through ``logging`` if no sink
+    is there or any handler can be reached from ``pio.trace`` (caplog,
+    an embedder's, an operator's): the sink took the place of the CLI's
+    ``StreamHandler`` and of nothing else."""
+    if extra:
+        if not _FIXED_FIELDS.isdisjoint(extra):
+            # a keyword that names a field already on the line replaces
+            # it where it stands, as ``dict.update`` did: rare enough to
+            # parse back (exact for any keyword, so one set serves both
+            # kinds of line)
+            record = json.loads(head + "}")
+            record.update(extra)
+            head = json.dumps(record, separators=(",", ":"))[:-1]
+        else:
+            head += "".join([f",{_json(k)}:{_json(v)}"
+                             for k, v in extra.items()])
+    line = head + "}"
+    sink = _sink
+    if sink is not None:
+        sink.put(line)
+        if not span_logger.hasHandlers():
+            return
+    span_logger.info("%s", line)
 
 
 def log_span(server: str, method: str, route: str, status: int,
@@ -204,49 +363,38 @@ def log_span(server: str, method: str, route: str, status: int,
     level so a silenced logger costs one attribute read per request.
     ``span_id``/``parent_span_id`` carry the cross-process parenting
     contract: the downstream hop's line names the upstream span, so
-    span lines from multiple processes link into one request tree."""
+    span lines from multiple processes link into one request tree.
+    The line is one string format, byte for byte what ``json.dumps`` of
+    the same fields (separators ``,`` and ``:``) gives."""
     if not span_logger.isEnabledFor(logging.INFO):
         return
-    record = {
-        "span": "http.request",
-        "server": server,
-        "method": method,
-        "route": route,
-        "status": status,
-        # wall stamp (epoch s, ms precision): cross-PROCESS span lines
-        # have no shared log stream, so the stitcher orders them by
-        # wall clock — NTP-grade skew is fine at request granularity
-        "ts": round(time.time(), 3),
-        "durationMs": round(duration_s * 1e3, 3),
-        "traceId": trace_id,
-    }
+    # ts: wall stamp (epoch s, ms precision): cross-PROCESS span lines
+    # have no shared log stream, so the stitcher orders them by wall
+    # clock — NTP-grade skew is fine at request granularity
+    head = (f'{{"span":"http.request","server":{_json(server)}'
+            f',"method":{_json(method)},"route":{_json(route)}'
+            f',"status":{_json(status)},"ts":{_json(round(time.time(), 3))}'
+            f',"durationMs":{_json(round(duration_s * 1e3, 3))}'
+            f',"traceId":{_json(trace_id)}')
     if span_id is not None:
-        record["spanId"] = span_id
+        head += f',"spanId":{_json(span_id)}'
     if parent_span_id is not None:
-        record["parentSpanId"] = parent_span_id
-    if extra:
-        record.update(extra)
-    span_logger.info("%s", json.dumps(record, separators=(",", ":")))
+        head += f',"parentSpanId":{_json(parent_span_id)}'
+    _emit(head, extra)
 
 
 def log_stage_span(span: str, trace_id: str, duration_s: float,
                    **extra: Any) -> None:
     """Emit a non-HTTP pipeline-stage span (the speed layer's freshness
-    chain: ``speed.poll`` → ``speed.foldin`` → ``speed.serve``) on the
-    same ``pio.trace`` logger and with the same shape as the request
-    spans, so one trace ID joins an event's whole journey across log
-    lines. Pre-gated like :func:`log_span`."""
+    chain: ``speed.poll`` → ``speed.foldin`` → ``speed.serve``) with the
+    same shape and through the same sink as the request spans, so one
+    trace ID joins an event's whole journey across log lines, in the
+    order they were made. Pre-gated like :func:`log_span`."""
     if not span_logger.isEnabledFor(logging.INFO):
         return
-    record = {
-        "span": span,
-        "ts": round(time.time(), 3),
-        "durationMs": round(duration_s * 1e3, 3),
-        "traceId": trace_id,
-    }
-    if extra:
-        record.update(extra)
-    span_logger.info("%s", json.dumps(record, separators=(",", ":")))
+    _emit(f'{{"span":{_json(span)},"ts":{_json(round(time.time(), 3))}'
+          f',"durationMs":{_json(round(duration_s * 1e3, 3))}'
+          f',"traceId":{_json(trace_id)}', extra)
 
 
 # ---------------------------------------------------------------------------

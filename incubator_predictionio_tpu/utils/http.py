@@ -529,9 +529,10 @@ class HttpServer:
         # one an operator most needs to find in the tree
         response.headers.setdefault(obs_trace.TRACE_HEADER, trace_id)
         response.headers.setdefault(obs_trace.SPAN_HEADER, span_id)
-        # span sampling (PIO_TRACE_SAMPLE): the JSON line is the one
-        # per-request cost that scales with QPS; sampled-out requests
-        # still got their trace ID stamped and echoed above
+        # span sampling (PIO_TRACE_SAMPLE): sampled-out requests still
+        # got their trace ID stamped and echoed above. Under the CLI the
+        # line is one format and one append here; its write is the span
+        # sink's own thread's (obs/trace.py)
         if obs_trace.span_sampled():
             obs_trace.log_span(self.name, request.method, route_label,
                                response.status, dt, trace_id,
@@ -675,7 +676,9 @@ class HttpServer:
         connections close at once, one with a request in flight after
         its response (``Connection: close``) or ``STOP_GRACE_S`` later.
         asyncio's ``serve_forever`` (`pio deploy`) returns only when
-        every connection has gone."""
+        every connection has gone. The span sink's writer is woken to
+        write what it holds (not waited for: this may be an event loop)."""
+        obs_trace.flush_span_log(wait=False)
         loop = self._loop
         if loop is not None and self._server is not None:
             try:

@@ -272,3 +272,40 @@ def test_sigterm_exits_through_interpreter():
     out, _ = p.communicate(timeout=15)
     assert p.returncode == 143
     assert "clean shutdown ran" in out
+
+
+def test_sigterm_inside_a_running_loop_is_raised_as_its_own_callback():
+    """A SIGTERM that arrives while the main thread runs an event loop
+    (every CLI server verb) must not raise in the middle of the loop's
+    code: a callback that has left the ready queue and not yet run is
+    lost with it, and ``asyncio.run``'s clean-up then waits for ever for
+    the task it would have woken. The step the signal met finishes, and
+    the loop ends with ``SystemExit(143)``."""
+    import asyncio
+    import signal
+    import threading
+
+    import pytest
+
+    from incubator_predictionio_tpu.utils.lease import install_sigterm_exit
+
+    if threading.current_thread() is not threading.main_thread():
+        pytest.skip("signal handlers belong to the main thread")
+    steps = []
+
+    async def main():
+        signal.raise_signal(signal.SIGTERM)     # the handler runs here
+        steps.append("the step the signal met went on")
+        await asyncio.sleep(30)
+        steps.append("never")
+
+    before = signal.getsignal(signal.SIGTERM)
+    try:
+        assert install_sigterm_exit()
+        with pytest.raises(SystemExit) as e:
+            asyncio.run(main())
+    finally:
+        signal.signal(signal.SIGTERM, before)
+    assert e.value.code == 143
+    assert steps == ["the step the signal met went on"]
+

@@ -45,6 +45,7 @@ from incubator_predictionio_tpu.data.storage.base import Interactions
 from incubator_predictionio_tpu.data.store import EventStore
 from incubator_predictionio_tpu.obs.trace import stage
 from incubator_predictionio_tpu.parallel.context import RuntimeContext
+from incubator_predictionio_tpu.utils.item_scores import render_item_scores
 
 logger = logging.getLogger(__name__)
 
@@ -832,9 +833,6 @@ class ALSAlgorithm(Algorithm):
         object path (pinned by tests/test_prediction_server.py); anything
         else (extra keys, unknown user, filters) stays None and falls to
         the object path."""
-        import json as _json
-        import math
-
         get_row = model.user_bimap.get
         ov = self.speed_overlay
         plain = []  # (slot, row, num)
@@ -860,32 +858,15 @@ class ALSAlgorithm(Algorithm):
         tops = self._score_plain_batch(model, rows, k)
         inv = model.item_bimap.inverse
         years = model.item_years
-        dumps = _json.dumps
-        isfinite = math.isfinite
+
+        def year(iid):
+            y = years.get(iid)
+            return ', "creationYear": ' + ("null" if y is None else repr(y))
+
         with stage("serve.render"):
             for (slot, _row, num), (top_s, top_i) in zip(plain, tops):
-                parts = []
-                ok = True
-                for s, i in zip(top_s[:num].tolist(),
-                                top_i[:num].tolist()):
-                    if s > -1e37:
-                        if not isfinite(s):
-                            # repr(inf) is not JSON (json.dumps says
-                            # 'Infinity') — an overflowed score falls
-                            # back to the object path rather than diverge
-                            ok = False
-                            break
-                        iid = inv[i]
-                        y = years.get(iid)
-                        # mirror json.dumps' default formatting exactly
-                        # (', '/': ' separators, float repr)
-                        parts.append('{"item": %s, "score": %s, '
-                                     '"creationYear": %s}'
-                                     % (dumps(iid), repr(s),
-                                        "null" if y is None else repr(y)))
-                if ok:
-                    out[slot] = ('{"itemScores": [' + ", ".join(parts)
-                                 + "]}").encode("utf-8")
+                out[slot] = render_item_scores(top_s, top_i, num,
+                                               inv.__getitem__, year)
         return out
 
 

@@ -14,6 +14,19 @@ each user's time-ordered item-event sequence from the event store.
 - Long sessions are first-class: ``seq_parallel`` ∈ {none, ring, ulysses}
   selects sequence/context parallelism over the mesh's ``sp`` axis
   (parallel/ring.py) for training on long histories.
+- The block is a description (ops/transformer.py ``BlockSpec``): the
+  SASRec block this engine trains, or — restored by a loader — a
+  published language-model block (sliding-window and full grouped-query
+  layers over routed experts).
+- Serving keeps every known user's window RESIDENT on the device
+  (``SeqRecModel.windows``): a plain ``{"user", "num"}`` query costs no
+  event-store read, the scheduler fuses such queries
+  (``batch_serve_json``) and one dispatch is one launch — the batch's
+  rows as the program's own argument, the windows gathered on the device
+  — and one fetch. One rule the code can observe decides: a query with
+  ``recentItems``, an unknown user, or a store whose write cursor moved
+  since the model was prepared takes the object path and reads the live
+  history, as before.
 """
 
 from __future__ import annotations
@@ -37,9 +50,27 @@ from incubator_predictionio_tpu.core import (
 )
 from incubator_predictionio_tpu.data.bimap import BiMap
 from incubator_predictionio_tpu.data.store import EventStore
+from incubator_predictionio_tpu.obs import metrics as obs_metrics
+from incubator_predictionio_tpu.obs.trace import stage
 from incubator_predictionio_tpu.parallel.context import RuntimeContext
+from incubator_predictionio_tpu.utils.item_scores import render_item_scores
 
 logger = logging.getLogger(__name__)
+
+# booked once a dispatch from the fetched array, nothing a request
+_TOKENS = obs_metrics.REGISTRY.counter(
+    "pio_seq_tokens_total",
+    "window positions the sequence engine's serving dispatches ran, "
+    "the padding rows of a rung included")
+_PAD_TOKENS = obs_metrics.REGISTRY.counter(
+    "pio_seq_pad_tokens_total",
+    "of pio_seq_tokens_total, the positions that did no work for an "
+    "answer: PAD inside a window, and every position of a padding row")
+_EXPERT_TOKENS = obs_metrics.REGISTRY.counter(
+    "pio_seq_moe_expert_tokens_total",
+    "tokens the routed feed-forward sent to each expert, summed over "
+    "layers (padding rows included: the device routed them)",
+    labels=("expert",))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,6 +113,8 @@ class DataSourceParams(Params):
 class TrainingData:
     #: per-user time-ordered item id sequences
     sessions: List[List[str]]
+    #: the user of each session, aligned (empty: the sessions are nameless)
+    users: List[str] = dataclasses.field(default_factory=list)
 
     def sanity_check(self) -> None:
         if not self.sessions:
@@ -106,13 +139,14 @@ class SequenceDataSource(DataSource):
                 per_user.setdefault(e.entity_id, []).append(
                     (e.event_time, e.target_entity_id)
                 )
-        sessions = []
-        for items in per_user.values():
+        sessions, users = [], []
+        for user, items in per_user.items():
             items.sort(key=lambda t: t[0])
             seq = [i for _, i in items]
             if len(seq) >= self.params.min_session_length:
                 sessions.append(seq)
-        return TrainingData(sessions=sessions)
+                users.append(user)
+        return TrainingData(sessions=sessions, users=users)
 
 
 @dataclasses.dataclass
@@ -120,6 +154,8 @@ class PreparedData:
     #: [N, max_len] int32, PAD(0)-left-padded, items indexed from 1
     sequences: np.ndarray
     item_bimap: BiMap
+    #: user → row of ``sequences`` (None: the rows are nameless)
+    user_bimap: Optional[BiMap] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +179,11 @@ class SequencePreparator(Preparator):
         for r, seq in enumerate(td.sessions):
             idx = [item_bimap[i] + 1 for i in seq][-max_len:]
             rows[r, max_len - len(idx):] = idx
-        return PreparedData(sequences=rows, item_bimap=item_bimap)
+        user_bimap = (BiMap({u: r for r, u in enumerate(td.users)})
+                      if len(td.users) == len(td.sessions) and td.users
+                      else None)
+        return PreparedData(sequences=rows, item_bimap=item_bimap,
+                            user_bimap=user_bimap)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,15 +203,29 @@ class SeqRecAlgorithmParams(Params):
     seq_parallel: str = "none"
     #: event types read to reconstruct a live session at serve time
     recent_events: Tuple[str, ...] = ("view", "buy")
+    #: the block's description (ops/transformer.py ``BlockSpec`` as
+    #: JSON) of a model a loader restores; None is the SASRec block of
+    #: ``d_model`` / ``n_heads`` / ``n_layers``, the one ``train`` fits
+    block: Optional[Dict[str, Any]] = None
+
+
+#: a model no ``prepare_model`` has seen serves nothing from residence
+_UNPREPARED = "unprepared"
 
 
 @dataclasses.dataclass
 class SeqRecModel:
-    weights: Any            # ops.transformer.TransformerWeights
+    #: ops.transformer.TransformerWeights, or BlockWeights under ``spec``
+    weights: Any
     item_bimap: BiMap
     n_heads: int
     max_len: int
     final_loss: float
+    #: ops.transformer.BlockSpec; None: the SASRec block of ``weights``
+    spec: Any = None
+    #: [n_users, window] int32 on the device: every known user's window
+    windows: Any = None
+    user_bimap: Optional[BiMap] = None
 
 
 class SeqRecAlgorithm(Algorithm):
@@ -242,6 +296,10 @@ class SeqRecAlgorithm(Algorithm):
     def train(self, ctx: RuntimeContext, pd: PreparedData) -> SeqRecModel:
         from incubator_predictionio_tpu.ops.transformer import sasrec_fit
 
+        if self.params.block is not None:
+            raise NotImplementedError(
+                "sequence: training fits the SASRec block; a described "
+                "block is restored by its loader, not trained here")
         seed = self.params.seed if self.params.seed is not None else ctx.seed
         weights, losses = sasrec_fit(
             pd.sequences,
@@ -263,6 +321,11 @@ class SeqRecAlgorithm(Algorithm):
             n_heads=self.params.n_heads,
             max_len=pd.sequences.shape[1],
             final_loss=float(losses[-1]),
+            # scored at the width training ran at (see _serve_len): the
+            # last max_len − 1 events of every user
+            windows=(pd.sequences[:, 1:] if pd.user_bimap is not None
+                     else None),
+            user_bimap=pd.user_bimap,
         )
 
     def prepare_model(self, ctx, model: SeqRecModel) -> SeqRecModel:
@@ -271,7 +334,48 @@ class SeqRecAlgorithm(Algorithm):
         model.weights = jax.tree_util.tree_map(
             lambda x: jax.device_put(jax.numpy.asarray(x)), model.weights
         )
+        if model.windows is not None:
+            model.windows = jax.device_put(
+                jax.numpy.asarray(model.windows, jax.numpy.int32))
+        # the windows stand for the store as it was now: a later write
+        # sends every query back to the live history
+        model._resident_version = self._store_version()
         return model
+
+    @staticmethod
+    def _block(model: SeqRecModel):
+        """(description, weights) the serving programs take; a trained
+        SASRec model's are derived once."""
+        if model.spec is not None:
+            return model.spec, model.weights
+        block = getattr(model, "_sasrec_block", None)
+        if block is None or block[2] is not model.weights:
+            from incubator_predictionio_tpu.ops.transformer import (
+                sasrec_block,
+            )
+
+            block = (*sasrec_block(model.weights, model.n_heads),
+                     model.weights)
+            model._sasrec_block = block
+        return block[0], block[1]
+
+    @staticmethod
+    def _serve_len(model: SeqRecModel) -> int:
+        """The width a window is scored at. The SASRec block scores at
+        ``max_len − 1`` — the width training ran at (sasrec_fit shifts
+        ``batch[:, :-1]`` → ``batch[:, 1:]``), so every positional-
+        embedding row used received gradients; a described block at its
+        own length."""
+        return model.spec.max_len if model.spec is not None \
+            else model.max_len - 1
+
+    def _resident(self, model: SeqRecModel) -> bool:
+        """Whether plain queries may be answered from the resident
+        windows: there are some, and the store has not been written since
+        ``prepare_model`` took them to stand for it."""
+        return (model.windows is not None and model.user_bimap is not None
+                and getattr(model, "_resident_version", _UNPREPARED)
+                == self._store_version())
 
     def _history(self, query: Query, model: SeqRecModel) -> List[int]:
         """Session history as model token ids, oldest first. The
@@ -311,39 +415,199 @@ class SeqRecAlgorithm(Algorithm):
     def warmup(self, model: SeqRecModel, max_batch: int = 1) -> None:
         """Pre-compile the serving forward (core/base.py Algorithm.warmup):
         the transformer's first query otherwise pays the full XLA compile
-        — the most expensive cold path of any template. Uses an explicit
-        one-item history so no event-store read happens."""
+        — the most expensive cold path of any template. The explicit-
+        history program once (a one-item history, so no event-store read
+        happens), then the resident-window program at every width
+        ``_score_rows`` can launch (:meth:`_widths`)."""
         first = next(iter(model.item_bimap), None)
         if first is not None:
             self.predict(model, Query(user="__warmup__", num=10,
                                       recent_items=(str(first),)))
+        user = next(iter(model.user_bimap), None) \
+            if self._resident(model) else None
+        if user is None or int(max_batch) <= 0:
+            return  # nothing resident, or micro-batching disabled
+        q = Query(user=str(user), num=10)
+        for size in self._widths(int(max_batch)):
+            self.batch_predict(model, [(i, q) for i in range(size)])
 
-    def predict(self, model: SeqRecModel, query: Query) -> PredictedResult:
-        import jax.numpy as jnp
+    # -- scoring: two programs, one readout ---------------------------------
+    #: a batch this wide or narrower runs at its own width. The factor
+    #: engines pad a batch to the next power of two because a dispatch
+    #: streams the item table once whatever its width; here a padded row
+    #: is a whole forward over its window (13.3 ms of a 17–112 ms
+    #: dispatch at the measured configuration, PERF.md §6 PR 33), so three
+    #: queries fused must not pay for four
+    EXACT_WIDTHS = 8
 
-        from incubator_predictionio_tpu.ops.transformer import sasrec_topk
+    @classmethod
+    def _width(cls, n: int) -> int:
+        """The program width a batch of ``n`` resident rows runs at."""
+        from incubator_predictionio_tpu.ops.topk import next_pow2
 
-        hist = self._history(query, model)
-        if not hist:
-            return PredictedResult(item_scores=())
-        # Score at width max_len-1 — the width training ran at
-        # (sasrec_fit shifts batch[:, :-1] → batch[:, 1:]), so every
-        # positional-embedding row used here received gradients.
-        window = model.max_len - 1
+        return n if n <= cls.EXACT_WIDTHS else next_pow2(n)
+
+    @classmethod
+    def _widths(cls, cap: int) -> Tuple[int, ...]:
+        """Every width :meth:`_width` gives a batch of up to ``cap`` rows
+        (the scheduler's ladder cap): what ``warmup`` compiles."""
+        from incubator_predictionio_tpu.ops.topk import ladder_rungs
+
+        rungs = ladder_rungs(cap)
+        return tuple(range(1, min(rungs[-1], cls.EXACT_WIDTHS) + 1)) \
+            + tuple(r for r in rungs if r > cls.EXACT_WIDTHS)
+
+    @staticmethod
+    def _k_pad(model: SeqRecModel, k: int) -> int:
+        from incubator_predictionio_tpu.ops.topk import next_pow2
+
+        # pow2 like the factor engines' dispatch, so varying ``num``
+        # compiles O(log catalog) variants
+        return min(next_pow2(int(k)), len(model.item_bimap))
+
+    @staticmethod
+    def _book(n_real: int, n_pad: int, width: int, pads, routed) -> None:
+        _TOKENS.inc(float(n_pad * width))
+        _PAD_TOKENS.inc(float(int(pads[:n_real].sum())
+                              + (n_pad - n_real) * width))
+        if routed.size:
+            for expert, n in enumerate(routed.sum(axis=0).tolist()):
+                _EXPERT_TOKENS.labels(expert=expert).inc(float(n))
+
+    def _score_rows(self, model: SeqRecModel, rows, k: int):
+        """Per-row ``(scores, item tokens)`` for resident users ``rows``:
+        ONE launch — the padded int32 rows go up as the jitted program's
+        own argument (the factor engines' pattern, ops/topk
+        ``batch_score_top_k``) and the windows are gathered on the device
+        — and ONE fetch."""
+        from incubator_predictionio_tpu.ops.transformer import (
+            block_top_k_rows,
+            unpack_top_k,
+        )
+
+        spec, weights = self._block(model)
+        n = len(rows)
+        pad = self._width(n)
+        rows_np = np.asarray(rows, np.int32).reshape(n)
+        if pad > n:
+            rows_np = np.concatenate(
+                [rows_np, np.full(pad - n, rows_np[0], np.int32)])
+        k_pad = self._k_pad(model, k)
+        with stage("serve.launch"):
+            on_device = block_top_k_rows(spec, weights, model.windows,
+                                         rows_np, k_pad)
+        with stage("serve.fetch"):
+            packed = np.asarray(on_device)     # ONE fetch
+        top_s, top_i, pads, routed = unpack_top_k(packed, pad, k_pad, spec)
+        self._book(n, pad, model.windows.shape[1], pads, routed)
+        return [(top_s[b], top_i[b]) for b in range(n)]
+
+    def _score_tokens(self, model: SeqRecModel, hist: List[int], k: int):
+        """``(scores, item tokens)`` for one explicit history."""
+        from incubator_predictionio_tpu.ops.transformer import (
+            block_top_k_tokens,
+            unpack_top_k,
+        )
+
+        spec, weights = self._block(model)
+        window = self._serve_len(model)
         tokens = np.zeros((1, window), np.int32)
         hist = hist[-window:]
         tokens[0, window - len(hist):] = hist
-        k = min(query.num, len(model.item_bimap))
-        scores, ids = sasrec_topk(
-            model.weights, jnp.asarray(tokens), model.n_heads, k=k
-        )
+        k_pad = self._k_pad(model, k)
+        packed = np.asarray(block_top_k_tokens(spec, weights, tokens,
+                                               k_pad))
+        top_s, top_i, pads, routed = unpack_top_k(packed, 1, k_pad, spec)
+        self._book(1, 1, window, pads, routed)
+        return top_s[0], top_i[0]
+
+    @staticmethod
+    def _pack(model: SeqRecModel, scores, tokens) -> PredictedResult:
         inv = model.item_bimap.inverse
-        out = []
-        for s, i in zip(np.asarray(scores[0]), np.asarray(ids[0])):
-            if not np.isfinite(s) or int(i) == 0:
-                continue
-            out.append(ItemScore(item=inv[int(i) - 1], score=float(s)))
-        return PredictedResult(item_scores=tuple(out))
+        return PredictedResult(item_scores=tuple(
+            ItemScore(item=inv[int(i) - 1], score=float(s))
+            for s, i in zip(scores, tokens)
+            if s > -1e37 and int(i) != 0))  # masked filler, PAD
+
+    def _resident_row(self, model: SeqRecModel, query: Query):
+        if query.recent_items is not None:
+            return None
+        return model.user_bimap.get(query.user)
+
+    def predict(self, model: SeqRecModel, query: Query) -> PredictedResult:
+        k = min(query.num, len(model.item_bimap))
+        if k <= 0:
+            return PredictedResult(item_scores=())
+        row = self._resident_row(model, query) \
+            if self._resident(model) else None
+        if row is not None:
+            top_s, top_i = self._score_rows(model, [row], k)[0]
+        else:
+            hist = self._history(query, model)
+            if not hist:
+                return PredictedResult(item_scores=())
+            top_s, top_i = self._score_tokens(model, hist, k)
+        return self._pack(model, top_s[:query.num], top_i[:query.num])
+
+    def batch_predict(
+        self, model: SeqRecModel, queries: Sequence[Tuple[int, Query]]
+    ) -> List[Tuple[int, PredictedResult]]:
+        """The resident users of the batch in one dispatch (the program
+        ``batch_serve_json`` runs); every other query through
+        ``predict``."""
+        out: List[Tuple[int, PredictedResult]] = []
+        plain = []
+        if self._resident(model):
+            plain = [(qx, q, row) for qx, q in queries if q.num > 0
+                     for row in [self._resident_row(model, q)]
+                     if row is not None]
+        if plain:
+            k = min(max(q.num for _qx, q, _r in plain),
+                    len(model.item_bimap))
+            tops = self._score_rows(model, [r for _qx, _q, r in plain], k)
+            for (qx, q, _row), (top_s, top_i) in zip(plain, tops):
+                out.append((qx, self._pack(model, top_s[:q.num],
+                                           top_i[:q.num])))
+        handled = {qx for qx, _ in out}
+        for qx, q in queries:
+            if qx not in handled:
+                out.append((qx, self.predict(model, q)))
+        return out
+
+    def batch_serve_json(self, model: SeqRecModel, docs):
+        """Columnar serving fast path (core/base.py batch_serve_json): the
+        plain ``{"user": ..., "num": ...}`` wire shape for RESIDENT users
+        renders straight from the dispatch's packed array to response
+        bytes, byte for byte what the object path gives; anything else
+        (``recentItems``, an unknown user, a store written since the
+        model was prepared) stays None and takes the object path."""
+        if not self._resident(model):
+            return None
+        get_row = model.user_bimap.get
+        plain = []  # (slot, row, num)
+        with stage("serve.lookup"):
+            for slot, d in enumerate(docs):
+                if (type(d) is dict and len(d) == 2 and "user" in d
+                        and "num" in d):
+                    u, num = d["user"], d["num"]
+                    if (isinstance(u, str) and isinstance(num, int)
+                            and not isinstance(num, bool) and num > 0):
+                        row = get_row(u)
+                        if row is not None:
+                            plain.append((slot, row, num))
+        out: list = [None] * len(docs)
+        if not plain:
+            return out
+        k = min(max(num for _s, _r, num in plain), len(model.item_bimap))
+        tops = self._score_rows(model, [r for _s, r, _n in plain], k)
+        inv = model.item_bimap.inverse
+        with stage("serve.render"):
+            # a masked slot (PAD, the window's own items) scores NEG_INF
+            # and is left out; token t is item t − 1
+            for (slot, _row, num), (top_s, top_i) in zip(plain, tops):
+                out[slot] = render_item_scores(
+                    top_s, top_i, num, lambda t: inv[t - 1])
+        return out
 
 
 class HitAtK(AverageMetric):

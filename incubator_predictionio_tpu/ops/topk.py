@@ -353,6 +353,20 @@ def ladder_rungs(cap: int) -> Tuple[int, ...]:
     return tuple(1 << i for i in range(cap.bit_length()))
 
 
+#: further jitted serving programs (another engine's whole forward) that
+#: count towards :func:`serve_compile_cache_size`
+_COUNTED_PROGRAMS: list = []
+
+
+def count_serve_program(fn):
+    """Have ``serve_compile_cache_size`` count the jitted ``fn`` too: an
+    engine whose serving dispatch is a program of its own (the sequence
+    engine's forward, ops/transformer.py) registers it here, so that the
+    zero-recompile contract sees its ladder as it sees this module's."""
+    _COUNTED_PROGRAMS.append(fn)
+    return fn
+
+
 def serve_compile_cache_size() -> int:
     """Compiled serving-dispatch variants resident in this process —
     the scheduler's zero-steady-state-recompile contract counter (the
@@ -363,7 +377,7 @@ def serve_compile_cache_size() -> int:
         int(fn._cache_size())
         for fn in (top_k_with_exclusions, _score_and_top_k_xla,
                    _score_user_top_k_xla, _batch_score_top_k_xla,
-                   _sharded_topk_jit)
+                   _sharded_topk_jit, *_COUNTED_PROGRAMS)
     ) + _mips.mips_compile_cache_size()
 
 

@@ -122,8 +122,11 @@ def _collect_compile_cache() -> None:
     import sys as _sys
 
     mod = _sys.modules.get("incubator_predictionio_tpu.ops.topk")
-    if mod is not None:
-        _COMPILE_CACHE.set(float(mod.serve_compile_cache_size()))
+    # a module another thread is still importing is in sys.modules
+    # before its functions are: nothing is set until it has this one
+    size = getattr(mod, "serve_compile_cache_size", None)
+    if size is not None:
+        _COMPILE_CACHE.set(float(size()))
 
 
 obs_metrics.REGISTRY.register_collector("serve_compile_cache",

@@ -1,6 +1,7 @@
 """What the benchmark measures by lives partly in the program: the
-reduction from trace to ``score_roofline`` finds the scoring program by
-its XLA module name (``benchmark/configs/*.json`` ``scoring_module``).
+reduction from trace to ``score_roofline`` (the factor engines) and to
+``seq_forward_roofline`` (the sequence engine) finds the scoring program
+by its XLA module name (``benchmark/configs/*.json`` ``scoring_module``).
 A rename of the jitted function would leave the tests green and blind
 the metric on the chip; this fails here instead."""
 
@@ -21,10 +22,15 @@ def test_the_benchmark_has_configurations():
 
 @pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
 def test_scoring_module_names_the_jitted_scoring_program(path):
-    from incubator_predictionio_tpu.ops import topk
+    from incubator_predictionio_tpu.ops import topk, transformer
 
     with open(path) as f:
         config = json.load(f)
-    # XLA names a jitted function's module "jit_" + its __name__
-    module = "jit_" + topk._batch_score_top_k_xla.__name__
-    assert config["scoring_module"] in module
+    # XLA names a jitted function's module "jit_" + its __name__; a
+    # configuration names the fused dispatch of its own engine
+    program = {
+        "RecommendationEngine": topk._batch_score_top_k_xla,
+        "SequenceEngine": transformer.block_top_k_rows,
+    }[config["engine_factory"].rsplit(":", 1)[1]]
+    assert config["scoring_module"] in "jit_" + program.__name__
+    assert transformer.block_top_k_rows in topk._COUNTED_PROGRAMS

@@ -194,6 +194,53 @@ def test_als_fused_kernel_lowers(shape, implicit, warm):
         *args, *extra.values())
 
 
+# -- the sequence block's kernels at published widths -------------------------
+
+@pytest.mark.parametrize("rows", [16_384, 49_152, 131_072])
+def test_expert_layer_kernel_compiles_at_published_widths(shape, rows,
+                                                          monkeypatch):
+    """ops/moe.grouped_swiglu on the route a TPU backend resolves — one
+    Pallas kernel a tile of 256 sorted rows, an expert's three tables in
+    VMEM — for 64 experts of [2304, 896], [2304, 896], [896, 2304] in
+    bfloat16: the routed rows (2,048 tokens × 8 a window) of one window,
+    of three (a batch runs at its own width) and of eight."""
+    from incubator_predictionio_tpu.ops import moe
+
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    weights = moe.ExpertWeights(
+        router=shape((2304, 64), BF16), w_gate=shape((64, 2304, 896), BF16),
+        w_up=shape((64, 2304, 896), BF16),
+        w_down=shape((64, 896, 2304), BF16))
+    compiled = compile_for_chip(moe.grouped_swiglu,
+                                shape((rows, 2304), BF16), weights,
+                                shape((64,), I32))
+    assert compiled.as_text().count("pio_moe_experts") >= 1
+
+
+@pytest.mark.parametrize("window", [1024, None])
+def test_attention_kernel_route_compiles_at_published_widths(shape, window):
+    """The sequence block's attention on a TPU: a projection's output
+    through the rotary pass into the kernel's layout, then jax's
+    splash-attention kernel over 32 query heads on 4 key-value heads of
+    128 at 2,048 positions, with padding keys' segment ids and a window."""
+    import functools
+
+    from incubator_predictionio_tpu.ops import attention
+
+    def route(q, k, v, cos, sin, valid):
+        return attention.kernel_attention(
+            attention.rotate_heads_first(q, cos, sin, 32, 128 ** -0.5),
+            attention.rotate_heads_first(k, cos, sin, 4), v,
+            kv_valid=valid, window=window)
+
+    compiled = compile_for_chip(
+        route, shape((2, 2048, 4096), BF16), shape((2, 2048, 512), BF16),
+        shape((2, 4, 2048, 128), BF16), shape((2048, 128), jnp.float32),
+        shape((2048, 128), jnp.float32), shape((2, 2048), jnp.bool_))
+    text = compiled.as_text()
+    assert "pio_rotate_heads_first" in text and "splash" in text
+
+
 # -- the main path's jitted programs at ML-20M shape -------------------------
 
 def test_als_train_program_compiles_at_ml20m_shape(shape, monkeypatch):

@@ -71,6 +71,12 @@ _EXPERT_TOKENS = obs_metrics.REGISTRY.counter(
     "tokens the routed feed-forward sent to each expert, summed over "
     "layers (padding rows included: the device routed them)",
     labels=("expert",))
+_ROWS_MULTIPLIED = obs_metrics.REGISTRY.counter(
+    "pio_seq_moe_rows_multiplied_total",
+    "rows the experts' products multiplied, summed over layers: on a TPU "
+    "every block of the kernel a group of routed rows touches, elsewhere "
+    "the routed rows; pio_seq_moe_expert_tokens_total summed over it is "
+    "the share of the products that were kept")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -466,13 +472,21 @@ class SeqRecAlgorithm(Algorithm):
         return min(next_pow2(int(k)), len(model.item_bimap))
 
     @staticmethod
-    def _book(n_real: int, n_pad: int, width: int, pads, routed) -> None:
+    def _book(n_real: int, n_pad: int, width: int, pads, routed,
+              dtype: str) -> None:
+        from incubator_predictionio_tpu.ops import moe
+
         _TOKENS.inc(float(n_pad * width))
         _PAD_TOKENS.inc(float(int(pads[:n_real].sum())
                               + (n_pad - n_real) * width))
         if routed.size:
             for expert, n in enumerate(routed.sum(axis=0).tolist()):
                 _EXPERT_TOKENS.labels(expert=expert).inc(float(n))
+            # every layer routes the dispatch's rows: one width, one block
+            m, n_experts = int(routed[0].sum()), routed.shape[1]
+            rows = moe.kernel_rows(m, n_experts) \
+                if moe.kernel_serves(m, dtype) else None
+            _ROWS_MULTIPLIED.inc(float(moe.rows_multiplied(routed, rows)))
 
     def _score_rows(self, model: SeqRecModel, rows, k: int):
         """Per-row ``(scores, item tokens)`` for resident users ``rows``:
@@ -499,7 +513,8 @@ class SeqRecAlgorithm(Algorithm):
         with stage("serve.fetch"):
             packed = np.asarray(on_device)     # ONE fetch
         top_s, top_i, pads, routed = unpack_top_k(packed, pad, k_pad, spec)
-        self._book(n, pad, model.windows.shape[1], pads, routed)
+        self._book(n, pad, model.windows.shape[1], pads, routed,
+                   spec.dtype)
         return [(top_s[b], top_i[b]) for b in range(n)]
 
     def _score_tokens(self, model: SeqRecModel, hist: List[int], k: int):
@@ -518,7 +533,7 @@ class SeqRecAlgorithm(Algorithm):
         packed = np.asarray(block_top_k_tokens(spec, weights, tokens,
                                                k_pad))
         top_s, top_i, pads, routed = unpack_top_k(packed, 1, k_pad, spec)
-        self._book(1, 1, window, pads, routed)
+        self._book(1, 1, window, pads, routed, spec.dtype)
         return top_s[0], top_i[0]
 
     @staticmethod

@@ -164,13 +164,11 @@ def test_the_tpu_attention_route_agrees_with_dense(window):
     assert not attention.kernel_attention_fits(jnp.bfloat16, 2048, 128)
 
 
-@pytest.mark.parametrize("sizes", [[100, 0, 300, 28, 0, 340, 256, 0],
-                                   [0, 0, 0, 0, 0, 0, 1, 1023],
-                                   [128] * 8])
-def test_the_tpu_expert_kernel_agrees_with_the_grouped_products(sizes):
-    """One kernel a tile of sorted rows, interpreted here, against three
-    ``ragged_dot``: tiles two experts share, empty experts, one row."""
-    e, d, f, rows = 8, 256, 128, 1024
+#: block heights :func:`ops.moe.kernel_rows` can return, and the tile's own
+KERNEL_HEIGHTS = (32, 64, 128, 256)
+
+
+def expert_tables(e=8, d=256, f=128, rows=1024):
     ks = jax.random.split(jax.random.key(1), 4)
     w = moe.ExpertWeights(
         router=None,
@@ -180,13 +178,68 @@ def test_the_tpu_expert_kernel_agrees_with_the_grouped_products(sizes):
               ).astype(jnp.bfloat16),
         w_down=(jax.random.normal(ks[2], (e, f, d)) * f ** -0.5
                 ).astype(jnp.bfloat16))
-    xs = jax.random.normal(ks[3], (rows, d), jnp.bfloat16)
+    return w, jax.random.normal(ks[3], (rows, d), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("sizes", [[100, 0, 300, 28, 0, 340, 256, 0],
+                                   [0, 0, 0, 0, 0, 0, 1, 1023],
+                                   [128] * 8,
+                                   # a group that ends inside the first
+                                   # block, one that starts inside the last
+                                   [3, 0, 500, 0, 0, 500, 0, 21],
+                                   # one expert has every row
+                                   [0, 0, 1024, 0, 0, 0, 0, 0]])
+def test_the_tpu_expert_kernel_agrees_with_the_grouped_products(sizes):
+    """One kernel over tiles of sorted rows, interpreted here, against
+    three ``ragged_dot``, at every block height: tiles and blocks two
+    experts share, blocks a visit skips, empty experts, one row."""
+    w, xs = expert_tables()
     group_sizes = jnp.asarray(sizes, jnp.int32)
     want = moe.grouped_swiglu(xs, w, group_sizes)
-    for tile in (128, 256):
-        got = moe._experts_pallas(xs, w, group_sizes, tile, interpret=True)
+    for rows in KERNEL_HEIGHTS:
+        got = moe._experts_pallas(xs, w, group_sizes, rows, interpret=True)
         assert np.abs(np.asarray(got, np.float32)
                       - np.asarray(want, np.float32)).max() < 0.02
+
+
+def test_a_rows_result_does_not_depend_on_the_block_it_rode_in():
+    w, xs = expert_tables()
+    group_sizes = jnp.asarray([100, 0, 300, 28, 0, 340, 255, 1], jnp.int32)
+    first, *others = (
+        np.asarray(moe._experts_pallas(xs, w, group_sizes, rows,
+                                       interpret=True), np.float32)
+        for rows in KERNEL_HEIGHTS)
+    for other in others:
+        assert np.array_equal(first, other)
+
+
+@pytest.mark.parametrize("windows", range(1, 9))
+def test_the_block_height_at_the_widths_the_cell_warms(windows):
+    """2,048 tokens × 8 experts a token a window, 64 experts: whole
+    tiles, whole blocks a tile, a height the kernel is tested at."""
+    m = windows * 2048 * 8
+    rows = moe.kernel_rows(m, 64)
+    assert rows in KERNEL_HEIGHTS
+    assert m % moe.KERNEL_TILE_ROWS == 0 and moe.KERNEL_TILE_ROWS % rows == 0
+    assert rows == (32 if windows == 1 else 64)
+
+
+@pytest.mark.parametrize("sizes", [
+    [256, 128, 128, 512, 0, 0, 0, 0],           # on block boundaries
+    [0, 0, 0, 1024, 0, 0, 0, 0],                # all in one expert
+    [100, 0, 300, 28, 0, 340, 256, 0],          # empty experts between
+    [127, 129, 128, 128, 131, 125, 128, 128],   # balanced
+], ids=["aligned", "one-expert", "empty-experts", "balanced"])
+def test_multiplied_rows_against_a_count_block_by_block(sizes):
+    ends = np.cumsum(sizes)
+    for rows in (16, 64, 128, 256):
+        brute = sum(
+            rows for at in range(0, int(ends[-1]), rows)
+            for size, end in zip(sizes, ends)
+            if size and end - size < at + rows and end > at)
+        assert moe.rows_multiplied(sizes, rows) == brute
+    assert moe.rows_multiplied(sizes, None) == 1024
+    assert moe.rows_multiplied([256, 128, 128, 512, 0, 0, 0, 0], 128) == 1024
 
 
 def test_blockwise_with_a_window_differentiates():
@@ -446,6 +499,35 @@ def test_counters_are_booked_once_a_dispatch(served):
     assert t1 - t0 == 3 * LENGTH
     assert p1 - p0 == 5
     assert e1 - e0 == 3 * LENGTH * 2 * 4      # rows × top-2 × four layers
+
+
+def test_multiplied_rows_are_booked_beside_the_routed(served, monkeypatch):
+    """Off a TPU three ``ragged_dot`` multiply the routed rows and nothing
+    else; at a width the TPU kernel serves, every block a group touches."""
+    from incubator_predictionio_tpu.obs import metrics
+    from incubator_predictionio_tpu.ops import pallas_kernels
+
+    algo, model = served
+    multiplied = metrics.REGISTRY.get("pio_seq_moe_rows_multiplied_total")
+    before = multiplied.value
+    algo.batch_serve_json(model, [{"user": u, "num": 10}
+                                  for u in ("u0", "u5", "u6")])
+    assert multiplied.value - before == 3 * LENGTH * 2 * 4
+    # two layers of 512 rows over four experts, as a TPU would run them
+    monkeypatch.setattr(pallas_kernels, "pallas_available", lambda: True)
+    routed = np.asarray([[100, 300, 0, 112], [128, 128, 128, 128]])
+    rows = moe.kernel_rows(512, 4)
+    before = multiplied.value
+    SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
+                          "bfloat16")
+    assert multiplied.value - before == sum(
+        moe.rows_multiplied(layer, rows) for layer in routed)
+    assert moe.rows_multiplied(routed[0], rows) > 512
+    # float32 rows take the grouped products there too
+    before = multiplied.value
+    SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
+                          "float32")
+    assert multiplied.value - before == 1024
 
 
 @pytest.mark.parametrize("cap, widths", [

@@ -196,14 +196,18 @@ def test_als_fused_kernel_lowers(shape, implicit, warm):
 
 # -- the sequence block's kernels at published widths -------------------------
 
-@pytest.mark.parametrize("rows", [16_384, 49_152, 131_072])
+@pytest.mark.parametrize("rows", [16_384, 32_768, 49_152, 65_536,
+                                  131_072])
 def test_expert_layer_kernel_compiles_at_published_widths(shape, rows,
                                                           monkeypatch):
     """ops/moe.grouped_swiglu on the route a TPU backend resolves — one
-    Pallas kernel a tile of 256 sorted rows, an expert's three tables in
-    VMEM — for 64 experts of [2304, 896], [2304, 896], [896, 2304] in
-    bfloat16: the routed rows (2,048 tokens × 8 a window) of one window,
-    of three (a batch runs at its own width) and of eight."""
+    Pallas kernel over tiles of 256 sorted rows in blocks of the height
+    ``kernel_rows`` gives that width, two experts' three tables in VMEM —
+    for 64 experts of [2304, 896], [2304, 896], [896, 2304] in bfloat16:
+    the routed rows (2,048 tokens × 8 a window) of one window, of two,
+    three (a batch runs at its own width), four and eight. The compiled
+    text holds an op named ``pio_moe_experts``: what the benchmark's
+    ``moe_ops`` reads the kernel's device time by."""
     from incubator_predictionio_tpu.ops import moe
 
     monkeypatch.setattr(pk, "pallas_available", lambda: True)

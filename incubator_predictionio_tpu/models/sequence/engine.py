@@ -77,12 +77,6 @@ _ROWS_MULTIPLIED = obs_metrics.REGISTRY.counter(
     "every block of the kernel a group of routed rows touches, elsewhere "
     "the routed rows; pio_seq_moe_expert_tokens_total summed over it is "
     "the share of the products that were kept")
-_ROWS_COMBINED = obs_metrics.REGISTRY.counter(
-    "pio_seq_moe_rows_combined_total",
-    "routed rows whose un-sort and weighted sum the combine kernel did, "
-    "summed over layers (on a TPU where ops/moe.combine_serves; elsewhere "
-    "none); over pio_seq_moe_expert_tokens_total summed it is the share "
-    "of routed rows that took the kernel")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -479,7 +473,7 @@ class SeqRecAlgorithm(Algorithm):
 
     @staticmethod
     def _book(n_real: int, n_pad: int, width: int, pads, routed,
-              dtype: str, d_model: int) -> None:
+              dtype: str) -> None:
         from incubator_predictionio_tpu.ops import moe
 
         _TOKENS.inc(float(n_pad * width))
@@ -493,9 +487,6 @@ class SeqRecAlgorithm(Algorithm):
             rows = moe.kernel_rows(m, n_experts) \
                 if moe.kernel_serves(m, dtype) else None
             _ROWS_MULTIPLIED.inc(float(moe.rows_multiplied(routed, rows)))
-            combined = m * routed.shape[0] if moe.combine_serves(
-                m, d_model, dtype) else 0
-            _ROWS_COMBINED.inc(float(combined))
 
     def _score_rows(self, model: SeqRecModel, rows, k: int):
         """Per-row ``(scores, item tokens)`` for resident users ``rows``:
@@ -521,9 +512,11 @@ class SeqRecAlgorithm(Algorithm):
                                          rows_np, k_pad)
         with stage("serve.fetch"):
             packed = np.asarray(on_device)     # ONE fetch
-        top_s, top_i, pads, routed = unpack_top_k(packed, pad, k_pad, spec)
-        self._book(n, pad, model.windows.shape[1], pads, routed,
-                   spec.dtype, spec.d_model)
+        with stage("serve.book"):
+            top_s, top_i, pads, routed = unpack_top_k(packed, pad, k_pad,
+                                                      spec)
+            self._book(n, pad, model.windows.shape[1], pads, routed,
+                       spec.dtype)
         return [(top_s[b], top_i[b]) for b in range(n)]
 
     def _score_tokens(self, model: SeqRecModel, hist: List[int], k: int):
@@ -542,7 +535,7 @@ class SeqRecAlgorithm(Algorithm):
         packed = np.asarray(block_top_k_tokens(spec, weights, tokens,
                                                k_pad))
         top_s, top_i, pads, routed = unpack_top_k(packed, 1, k_pad, spec)
-        self._book(1, 1, window, pads, routed, spec.dtype, spec.d_model)
+        self._book(1, 1, window, pads, routed, spec.dtype)
         return top_s[0], top_i[0]
 
     @staticmethod

@@ -46,7 +46,8 @@ import secrets
 import sys
 import threading
 import time
-from typing import Any, Deque, Dict, Optional, Tuple
+import weakref
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 from incubator_predictionio_tpu.obs import metrics as obs_metrics
 
@@ -74,6 +75,7 @@ _current_span: contextvars.ContextVar[Optional[str]] = \
 
 #: one JSON object per line; operators point this at their log shipper
 span_logger = logging.getLogger("pio.trace")
+logger = logging.getLogger(__name__)
 
 
 def new_trace_id() -> str:
@@ -414,11 +416,41 @@ _PHASE_SECONDS = obs_metrics.REGISTRY.counter(
 _phase_children: Dict[str, Any] = {}
 _annotation_cls: Any = None
 
+#: a stall's floor: a phase (``serve.wait`` aside), a beat of the event
+#: loop or a wake of the stall watch this far past its due is booked. A
+#: dispatch of the ALS cells is 2-5 ms, a full collection 38-48 ms
+STALL_S = 0.025
+#: 25 ms to 20 s in steps of at most 1.5, as ``pio_gc_pause_seconds``
+_STALL_BUCKETS = obs_metrics.geometric_buckets(STALL_S, 20.0)
+_PHASE_LONG = obs_metrics.REGISTRY.histogram(
+    "pio_serve_phase_long_seconds",
+    "one phase of the dispatcher's cycle whose own time passed 25 ms "
+    "(wait aside): the phases of pio_serve_phase_seconds_total, each run "
+    "that long observed alone", labels=("phase",), buckets=_STALL_BUCKETS)
+#: (phase, seconds) of the phases that ran long, booked at scrape time
+_long_phases: Deque[Tuple[str, float]] = collections.deque(maxlen=16384)
+
+
+class _ThreadStages:
+    """One thread's innermost open stage, where the stall watch reads it
+    from its own thread (one attribute read, nothing the owner pays)."""
+
+    __slots__ = ("top", "__weakref__")
+
+    def __init__(self) -> None:
+        self.top: Any = None
+
+
+#: {thread ident: that thread's stages}, for every live thread that ever
+#: opened a stage; an entry whose thread has ended reads None
+_stage_threads: Dict[int, "weakref.ref[_ThreadStages]"] = {}
+
 
 class _StageLocal(threading.local):
-    #: the innermost open stage on this thread (class default: a thread
-    #: that never opened one reads None without a miss)
-    top: Any = None
+    def __init__(self) -> None:
+        # runs once in each thread, on the thread's first stage
+        self.me = _ThreadStages()
+        _stage_threads[threading.get_ident()] = weakref.ref(self.me)
 
 
 _stage_local = _StageLocal()
@@ -442,8 +474,12 @@ def _annotation() -> Any:
 def _phase_child(name: str, phase: Optional[str]) -> Any:
     """The counter child a stage books under, cached by the stage's
     name: ``phase`` if given, else the name's last component."""
-    child = _phase_children[name] = _PHASE_SECONDS.labels(
-        phase=phase or name.rpartition(".")[2])
+    phase = phase or name.rpartition(".")[2]
+    child = _phase_children[name] = _PHASE_SECONDS.labels(phase=phase)
+    if name != "serve.wait":
+        # the phase's series is there before its first long run, so a
+        # reader tells "none ran long" from "not exported"
+        _PHASE_LONG.labels(phase=phase)
     return child
 
 
@@ -458,7 +494,9 @@ class stage:
     twice. ``phase`` defaults to the name's last component. The clock
     is read first on entry and last on exit: a stage's own bookkeeping
     is inside its time, so consecutive stages tile a thread's time to
-    within a microsecond. Called per dispatch, never per query."""
+    within a microsecond. A self time over ``STALL_S`` (``serve.wait``
+    aside) is queued for ``pio_serve_phase_long_seconds{phase}`` too: one
+    compare on the way out. Called per dispatch, never per query."""
 
     __slots__ = ("_name", "_phase", "_attrs", "_ann", "_parent", "_t0",
                  "_child_s")
@@ -472,7 +510,7 @@ class stage:
     def __enter__(self) -> "stage":
         self._t0 = time.perf_counter()
         self._child_s = 0.0
-        local = _stage_local
+        local = _stage_local.me
         parent = self._parent = local.top
         local.top = self
         self._ann = None
@@ -490,7 +528,7 @@ class stage:
     def __exit__(self, *exc: Any) -> None:
         if self._ann is not None:
             self._ann.__exit__(*exc)
-        parent = _stage_local.top = self._parent
+        parent = _stage_local.me.top = self._parent
         child = _phase_children.get(self._name) or _phase_child(
             self._name, self._phase)
         dt = time.perf_counter() - self._t0
@@ -498,6 +536,11 @@ class stage:
             parent._child_s += dt
         dt -= self._child_s
         child.inc(dt if dt > 0.0 else 0.0)
+        if dt > STALL_S and self._name != "serve.wait":
+            # a wait is idleness, however long; anything else this long
+            # is a stall (booked at scrape time: no lock here)
+            _long_phases.append(
+                (self._phase or self._name.rpartition(".")[2], dt))
 
 
 #: one collection of the serving process, start to stop, by generation:
@@ -592,3 +635,340 @@ def release_gc_hook() -> None:
             gc.callbacks.remove(_gc_hook)
             obs_metrics.REGISTRY.unregister_collector("gc_pause")
             _gc_hook.flush()
+
+
+# ---------------------------------------------------------------------------
+# stalls of the serving process, booked where they were seen
+# ---------------------------------------------------------------------------
+
+#: the stall watch's sleep and the heartbeat's period
+WATCH_PERIOD_S = 0.02
+#: the process held (a late wake of the watch) or the loop late this
+#: long writes a stall line
+STALL_LOG_S = 0.1
+#: a dispatcher phase open this long writes one: above the sequence
+#: cell's longest dispatch (eight windows, 112 ms)
+PHASE_LOG_S = 0.25
+#: frames of a thread a stall line carries, innermost last
+STALL_FRAMES = 6
+#: the heartbeat's clock, bound here: a test that stands a clock of its
+#: own in for this module's ``time`` moves the stages' clock alone
+_perf_counter = time.perf_counter
+
+_LOOP_LAG = obs_metrics.REGISTRY.histogram(
+    "pio_serve_loop_lag_seconds",
+    "a beat of the prediction server's event loop that ran over 25 ms "
+    "past its due: how long the loop's thread was kept from it",
+    buckets=_STALL_BUCKETS)
+_HOST_STALL = obs_metrics.REGISTRY.histogram(
+    "pio_host_stall_seconds",
+    "a wake of the stall watch over 25 ms past its due: no thread of the "
+    "serving process ran Python that long (one held the interpreter, or "
+    "the process or its host did not run)", buckets=_STALL_BUCKETS)
+#: seconds late of the loops' beats that ran long, booked at scrape time
+_loop_lags: Deque[float] = collections.deque(maxlen=16384)
+
+#: the stall line: one ``logging`` record a stall, never one a request,
+#: one JSON object (``{"stall": "host" | "loop" | "dispatcher", ...}``)
+#: like the span lines
+stall_logger = logging.getLogger("pio.stall")
+
+#: innermost frames of a thread that waits for work, not one that works
+_IDLE_FRAMES = frozenset((
+    ("threading.py", "wait"), ("selectors.py", "select"),
+    ("queue.py", "get"), ("thread.py", "_worker")))
+
+
+class LoopHeartbeat:
+    """A callback that re-arms itself on an event loop every
+    ``WATCH_PERIOD_S``: a beat that runs over ``STALL_S`` late books the
+    lateness in ``pio_serve_loop_lag_seconds`` (queued: booked at scrape
+    time), and every beat stamps ``last``, which the stall watch reads to
+    see a loop that is late right now. Fifty callbacks a second on the
+    loop, nothing a request."""
+
+    def __init__(self, loop: Any) -> None:
+        self.loop = loop
+        #: the loop's thread, known from the first beat
+        self.ident: Optional[int] = None
+        #: perf_counter of the last beat
+        self.last = _perf_counter()
+        #: set by the loop's server once its warm-up has ended: stall
+        #: lines before that are the warm-up's own
+        self.ready = False
+        self._due = self.last
+        self._handle: Any = None
+        self._stopped = False
+
+    def start(self) -> "LoopHeartbeat":
+        self.loop.call_soon_threadsafe(self._first)
+        return self
+
+    def _first(self) -> None:
+        self.ident = threading.get_ident()
+        self._arm(_perf_counter())
+
+    def _arm(self, now: float) -> None:
+        self.last = now
+        if not self._stopped:
+            self._due = now + WATCH_PERIOD_S
+            self._handle = self.loop.call_later(WATCH_PERIOD_S, self._beat)
+
+    def _beat(self) -> None:
+        now = _perf_counter()
+        late = now - self._due
+        if late > STALL_S:
+            _loop_lags.append(late)
+        self._arm(now)
+
+    def cancel(self) -> None:
+        """Stop re-arming, and cancel the armed beat on the loop's own
+        thread (from any thread; a closed loop has nothing armed)."""
+        self._stopped = True
+        try:
+            _heartbeats.remove(self)
+        except ValueError:
+            pass
+        try:
+            self.loop.call_soon_threadsafe(self._cancel_armed)
+        except RuntimeError:
+            pass
+
+    def _cancel_armed(self) -> None:
+        if self._handle is not None:
+            self._handle.cancel()
+
+
+def _frames(frame: Any) -> List[str]:
+    """The innermost ``STALL_FRAMES`` frames of a stack, innermost last,
+    as ``file:line function`` (no source read: a stall is no time to
+    touch the disk)."""
+    out = []
+    while frame is not None and len(out) < STALL_FRAMES:
+        code = frame.f_code
+        out.append(f"{code.co_filename.rsplit('/', 1)[-1]}:"
+                   f"{frame.f_lineno} {code.co_name}")
+        frame = frame.f_back
+    return out[::-1]
+
+
+class StallWatch:
+    """The thread ``pio-stall-watch``: it sleeps ``WATCH_PERIOD_S`` at a
+    time and, at each wake (:meth:`tick`),
+
+    - books a wake over ``STALL_S`` late in ``pio_host_stall_seconds``:
+      for that long no thread ran Python, because one held the
+      interpreter or because the process did not run. Which of the two
+      the watch does not say: a host that charges its own pauses to the
+      process as CPU time (as the benchmark's TPU hosts do) leaves the
+      process's CPU clock no witness. The line's frames name a holder
+      where there was one;
+    - reads each dispatcher thread's open stage and each loop's last
+      beat, which costs those threads nothing;
+    - writes one stall line (``pio.stall``) for a stall that passes its
+      log threshold: a late wake over ``STALL_LOG_S`` (or over
+      ``STALL_S`` with a loop over ``STALL_LOG_S`` late: the loop stood
+      through the same stall), a loop whose beat is that late now, a
+      phase open over ``PHASE_LOG_S``. A stall that began before the
+      last logged one ended is that one. Until a watched server is
+      ready the line is the warm-up's: INFO, with ``"warmup": true``.
+
+    The clocks are arguments, so a test can drive a wake by hand."""
+
+    def __init__(self, clock: Any = time.perf_counter,
+                 wall: Any = time.time) -> None:
+        self._clock, self._wall = clock, wall
+        self._t = 0.0
+        #: perf_counter at which the last logged stall ended
+        self._covered = 0.0
+        self._stop = threading.Event()
+        self._thread: Optional[threading.Thread] = None
+
+    def start(self) -> None:
+        self._stop.clear()
+        self._thread = threading.Thread(
+            target=self._run, name="pio-stall-watch", daemon=True)
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stop.set()
+        thread, self._thread = self._thread, None
+        if thread is not None:
+            thread.join(5.0)
+
+    def reset(self) -> None:
+        self._t = self._clock()
+
+    def _run(self) -> None:
+        self.reset()
+        while not self._stop.wait(WATCH_PERIOD_S):
+            try:
+                self.tick()
+            except Exception:  # the watch must outlive what it watches
+                logger.exception("stall watch: a wake failed")
+
+    def tick(self) -> Optional[Dict[str, Any]]:
+        """One wake: book, read, log. Returns the stall line's record,
+        where this wake wrote one."""
+        now = self._clock()
+        late = now - self._t - WATCH_PERIOD_S
+        self._t = now
+        if late > STALL_S:
+            _HOST_STALL.observe(late)
+        beats = self._beats(now)
+        opened = self._open_stages()
+        record = None
+        if late > STALL_LOG_S or late > STALL_S and any(
+                lag > STALL_LOG_S for _i, _l, lag in beats):
+            record = {"stall": "host", "start": now - late}
+        else:
+            record = self._ongoing(now, beats, opened)
+        if record is None:
+            return None
+        self._covered = now
+        return self._log(record, now, beats, opened)
+
+    @staticmethod
+    def _beats(now: float) -> List[Tuple[Optional[int], float, float]]:
+        """(loop thread, perf_counter of its last beat, seconds late now)
+        of every watched loop."""
+        return [(hb.ident, hb.last, now - hb.last - WATCH_PERIOD_S)
+                for hb in list(_heartbeats) if not hb.loop.is_closed()]
+
+    @staticmethod
+    def _open_stages() -> List[Tuple[int, Any]]:
+        """(thread, innermost open stage) of every thread inside a stage
+        other than ``serve.wait``; ended threads are dropped."""
+        out = []
+        for ident, ref in list(_stage_threads.items()):
+            stages = ref()
+            if stages is None:
+                if _stage_threads.get(ident) is ref:
+                    del _stage_threads[ident]
+                continue
+            top = stages.top
+            if top is not None and top._name != "serve.wait":
+                out.append((ident, top))
+        return out
+
+    def _ongoing(self, now: float, beats: list,
+                 opened: list) -> Optional[Dict[str, Any]]:
+        """A loop late or a phase open past its threshold now, that began
+        after the last logged stall ended."""
+        for _ident, last, lag in beats:
+            if lag > STALL_LOG_S and last >= self._covered:
+                return {"stall": "loop", "start": last + WATCH_PERIOD_S}
+        for _ident, top in opened:
+            if (now - top._t0 > PHASE_LOG_S and top._t0 >= self._covered
+                    and _dispatch_of(top) is not None):
+                return {"stall": "dispatcher", "start": top._t0}
+        return None
+
+    def _log(self, record: Dict[str, Any], now: float, beats: list,
+             opened: list) -> Dict[str, Any]:
+        wall = self._wall()
+        start = record.pop("start")
+        names = {t.ident: t.name for t in threading.enumerate()}
+        record.update(
+            ms=round((now - start) * 1e3, 3),
+            start=round(wall - (now - start), 3), end=round(wall, 3),
+            open=[], loopLagMs=round(
+                max([lag for _i, _l, lag in beats] + [0.0]) * 1e3, 3),
+            frames={})
+        for ident, top in opened:
+            record["open"].append({
+                "thread": f"{names.get(ident, '?')}/{ident}",
+                "stage": top._name, "id": _dispatch_of(top),
+                "openMs": round((now - top._t0) * 1e3, 3)})
+        # the dispatcher threads and the loops' own, always; for a host
+        # stall, whichever other thread stands in code of its own and not
+        # in a wait for work: a holder of the interpreter, if one held it
+        watched = set(_stage_threads) | {i for i, _l, _g in beats}
+        me = threading.get_ident()
+        for ident, frame in sys._current_frames().items():
+            stack = [] if ident == me else _frames(frame)
+            if not stack:
+                continue
+            if ident not in watched:
+                file_line, _sp, func = stack[-1].partition(" ")
+                if record["stall"] != "host" or (
+                        file_line.partition(":")[0], func) in _IDLE_FRAMES:
+                    continue
+            record["frames"][f"{names.get(ident, '?')}/{ident}"] = stack
+        loops = [hb for hb in list(_heartbeats) if not hb.loop.is_closed()]
+        warmup = bool(loops) and not any(hb.ready for hb in loops)
+        if warmup:
+            record["warmup"] = True
+        stall_logger.log(logging.INFO if warmup else logging.WARNING, "%s",
+                         json.dumps(record, separators=(",", ":"),
+                                    default=str))
+        return record
+
+
+def _dispatch_of(top: Any) -> Any:
+    """The ``id`` of the ``serve.dispatch`` an open stage lies in; None
+    outside a dispatch (a warm-up's stages)."""
+    while top is not None and "id" not in top._attrs:
+        top = top._parent
+    return None if top is None else top._attrs["id"]
+
+
+def _flush_stalls() -> None:
+    """Scrape time: the phases that ran long and the loops' late beats
+    since the last flush → their histograms, with no request's trace as
+    an exemplar (the ambient one is the scrape's own)."""
+    token = set_current(None)
+    try:
+        while True:
+            try:
+                phase, dt = _long_phases.popleft()
+            except IndexError:
+                break
+            _PHASE_LONG.labels(phase=phase).observe(dt)
+        while True:
+            try:
+                late = _loop_lags.popleft()
+            except IndexError:
+                break
+            _LOOP_LAG.observe(late)
+    finally:
+        reset_current(token)
+
+
+#: one watch a process, held while any prediction server serves (the
+#: GC hook's idiom); the loops it reads are each server's own, in a list
+#: that is only appended to, removed from and copied (no lock)
+_watch = StallWatch()
+_watch_lock = threading.Lock()
+_watch_holders = 0
+_heartbeats: List[LoopHeartbeat] = []
+
+
+def acquire_stall_watch() -> None:
+    global _watch_holders
+    with _watch_lock:
+        _watch_holders += 1
+        if _watch_holders == 1:
+            _watch.start()
+            obs_metrics.REGISTRY.register_collector("stall_watch",
+                                                    _flush_stalls)
+
+
+def release_stall_watch() -> None:
+    global _watch_holders
+    with _watch_lock:
+        if _watch_holders == 0:
+            return
+        _watch_holders -= 1
+        if _watch_holders == 0:
+            _watch.stop()
+            obs_metrics.REGISTRY.unregister_collector("stall_watch")
+            _flush_stalls()
+
+
+def watch_loop(loop: Any) -> LoopHeartbeat:
+    """A heartbeat on ``loop``, read by the stall watch until its
+    ``cancel()``."""
+    hb = LoopHeartbeat(loop)
+    _heartbeats.append(hb)
+    return hb.start()

@@ -20,6 +20,7 @@ restore models via ``Engine.prepare_deploy`` (device-resident) → bind.
 
 from __future__ import annotations
 
+import asyncio
 import dataclasses
 import json
 import logging
@@ -388,6 +389,7 @@ class PredictionServer:
         obs_metrics.REGISTRY.register_collector(
             "serve_ready", _collect_ready)
         self._gc_hook_held = False
+        self._heartbeat: Optional[obs_trace.LoopHeartbeat] = None
         # feedback events are training data: a deep queue so only a
         # sustained collector outage drops (drops counted and shown on the
         # status page); --log-url diagnostics stay shallow and lossy
@@ -1020,8 +1022,6 @@ class PredictionServer:
 
         @r.post("/queries.json")
         async def queries(request: Request) -> Response:
-            import asyncio
-
             from incubator_predictionio_tpu.utils.http import sync
 
             try:
@@ -1258,6 +1258,7 @@ class PredictionServer:
         event so warmup tracing never delays the bind (the foreground
         serve_forever path spawns this before the loop starts)."""
         algorithms, models = self.algorithms, self.models
+        heartbeat = self._heartbeat
 
         def run() -> None:
             try:
@@ -1273,18 +1274,28 @@ class PredictionServer:
                             time.perf_counter() - t0)
             finally:
                 self._ready = True
+                if heartbeat is not None:
+                    # stall lines from here on are the serving's own
+                    heartbeat.ready = True
 
         threading.Thread(target=run, daemon=True,
                          name="pio-serving-warmup").start()
 
-    def _watch_gc(self) -> None:
-        """Serving starts: book the collector's pauses from here on
-        (pio_gc_pause_seconds, the gc.pause annotation) until stop()."""
+    def _watch_gc(self, loop: Optional[asyncio.AbstractEventLoop] = None
+                  ) -> None:
+        """Serving starts: from here on until stop(), book the
+        collector's pauses (pio_gc_pause_seconds, the gc.pause
+        annotation) and the process's stalls (obs/trace's stall watch,
+        with a heartbeat on ``loop``, this server's event loop)."""
         with self._lock:
             held = getattr(self, "_gc_hook_held", False)
             self._gc_hook_held = True
         if not held:
             obs_trace.acquire_gc_hook()
+            obs_trace.acquire_stall_watch()
+            loop = loop or self.http._loop
+            self._heartbeat = (obs_trace.watch_loop(loop)
+                               if loop is not None else None)
 
     def start_background(self) -> int:
         self.load_models()
@@ -1298,7 +1309,7 @@ class PredictionServer:
     async def serve_forever(self) -> None:
         self.load_models()
         self.undeploy_existing()
-        self._watch_gc()
+        self._watch_gc(asyncio.get_running_loop())
         self._warmup_async()
         await self.http.serve_forever()
 
@@ -1312,6 +1323,9 @@ class PredictionServer:
             self._gc_hook_held = False
         if gc_held:
             obs_trace.release_gc_hook()
+            obs_trace.release_stall_watch()
+            if self._heartbeat is not None:
+                self._heartbeat.cancel()
         if held:
             try:
                 from incubator_predictionio_tpu.ops import mips_daemon

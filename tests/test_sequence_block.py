@@ -663,6 +663,72 @@ def test_counters_are_booked_once_a_dispatch(served):
     assert e1 - e0 == 3 * LENGTH * 2 * 4      # rows × top-2 × four layers
 
 
+def test_book_is_a_phase_and_the_phases_still_tile_the_thread(
+        served, monkeypatch):
+    """``serve.book`` (unpacking the fetched array, booking the counters
+    above) is a phase of its own, once a dispatch, and a dispatcher
+    thread's phases still sum to its life: the seconds leave ``other``,
+    none are added."""
+    from incubator_predictionio_tpu.obs import trace as obs_trace
+    from incubator_predictionio_tpu.serving.scheduler import BatchScheduler
+
+    algo, model = served
+    booked = []
+
+    class Child:
+        def __init__(self, phase):
+            self.phase = phase
+
+        def inc(self, n):
+            booked.append((self.phase, n))
+
+    class Children(dict):
+        def get(self, name, default=None):
+            if name not in self:
+                self[name] = Child("other" if name == "serve.dispatch"
+                                   else name.rpartition(".")[2])
+            return self[name]
+
+    class Clock:
+        now = 0.0
+
+        def perf_counter(self):
+            return self.now
+
+        def __getattr__(self, name):
+            return getattr(__import__("time"), name)
+
+    clock = Clock()
+    monkeypatch.setattr(obs_trace, "_phase_children", Children())
+    monkeypatch.setattr(obs_trace, "time", clock)
+    inner = SeqRecAlgorithm._book
+
+    def book(*args):
+        clock.now += 0.003
+        inner(*args)
+
+    monkeypatch.setattr(SeqRecAlgorithm, "_book", staticmethod(book))
+    s = BatchScheduler(lambda bodies: algo.batch_serve_json(model, bodies),
+                       4, shed=False, wait_bound_s=0.0)
+    thread = s._threads[0]
+    futs = [s.submit({"user": f"u{n}", "num": 10}) for n in range(5)]
+    assert all(f.result(60) is not None for f in futs)
+    s.stop()
+    thread.join(10)
+    assert not thread.is_alive()
+    by_phase = {}
+    for phase, sec in booked:
+        by_phase.setdefault(phase, []).append(sec)
+    assert {"wait", "other", "lookup", "launch", "fetch", "book",
+            "render", "complete"} <= set(by_phase)
+    dispatches = len(by_phase["other"])
+    assert len(by_phase["book"]) == dispatches
+    assert sum(by_phase["book"]) == pytest.approx(0.003 * dispatches)
+    assert sum(by_phase["other"]) == pytest.approx(0.0)
+    # every second of the thread's life is in one phase
+    assert sum(sec for _p, sec in booked) == pytest.approx(clock.now)
+
+
 def test_multiplied_rows_are_booked_beside_the_routed(served, monkeypatch):
     """Off a TPU three ``ragged_dot`` multiply the routed rows and nothing
     else; at a width the TPU kernel serves, every block a group touches."""
@@ -681,43 +747,15 @@ def test_multiplied_rows_are_booked_beside_the_routed(served, monkeypatch):
     rows = moe.kernel_rows(512, 4)
     before = multiplied.value
     SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
-                          "bfloat16", 256)
+                          "bfloat16")
     assert multiplied.value - before == sum(
         moe.rows_multiplied(layer, rows) for layer in routed)
     assert moe.rows_multiplied(routed[0], rows) > 512
     # float32 rows take the grouped products there too
     before = multiplied.value
     SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
-                          "float32", 256)
+                          "float32")
     assert multiplied.value - before == 1024
-
-
-def test_combined_rows_are_booked_once_a_dispatch(served, monkeypatch):
-    """One ``inc`` a dispatch: the routed rows of every layer where the
-    combine kernel serves, none where it does not (here, off a TPU)."""
-    from incubator_predictionio_tpu.models.sequence import engine
-    from incubator_predictionio_tpu.ops import pallas_kernels
-
-    algo, model = served
-    booked = []
-    counter = engine._ROWS_COMBINED
-    monkeypatch.setattr(engine, "_ROWS_COMBINED", type(
-        "Spy", (), {"inc": lambda self, n: (booked.append(n),
-                                            counter.inc(n))})())
-    algo.batch_serve_json(model, [{"user": u, "num": 10}
-                                  for u in ("u0", "u5", "u6")])
-    assert booked == [0.0]
-    # two layers of 512 rows over four experts, as a TPU would run them
-    monkeypatch.setattr(pallas_kernels, "pallas_available", lambda: True)
-    routed = np.asarray([[100, 300, 0, 112], [128, 128, 128, 128]])
-    before = counter.value
-    SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
-                          "bfloat16", 256)
-    assert booked[1:] == [1024.0] and counter.value - before == 1024
-    # a width whose packed halves are not whole lanes: the gather did it
-    SeqRecAlgorithm._book(1, 1, 64, np.zeros(1, np.int64), routed,
-                          "bfloat16", 64)
-    assert booked[2:] == [0.0] and counter.value - before == 1024
 
 
 @pytest.mark.parametrize("cap, widths", [

@@ -504,6 +504,47 @@ def test_ready_gc_pause_and_device_gauges(als_server):
     assert (obs_trace._gc_hook in gc.callbacks) == (holders > 1)
 
 
+def _watch_threads():
+    return [t for t in threading.enumerate()
+            if t.name == "pio-stall-watch" and t.is_alive()]
+
+
+def test_two_servers_share_one_stall_watch_until_the_last_stops(
+        als_server, monkeypatch):
+    # a watch of this test's own: a server another test left serving
+    # holds the process's, and would keep it alive
+    monkeypatch.setattr(obs_trace, "_watch", obs_trace.StallWatch())
+    monkeypatch.setattr(obs_trace, "_watch_holders", 0)
+    monkeypatch.setattr(obs_trace, "_heartbeats", [])
+    monkeypatch.setattr(obs_trace, "_gc_holders", 0)
+    collectors = obs_metrics.REGISTRY._collectors
+    first, second = als_server(), als_server()
+    for ps in (first, second):
+        # warmed: no warm-up thread of these servers outlives the test
+        _wait_ready(ps.start_background())
+    assert obs_trace._watch_holders == 2
+    assert len(_watch_threads()) == 1
+    beats = [first._heartbeat, second._heartbeat]
+    assert obs_trace._heartbeats == beats
+    assert {"stall_watch", "gc_pause"} <= set(collectors)
+    first.stop()
+    assert obs_trace._watch_holders == 1
+    assert len(_watch_threads()) == 1
+    assert obs_trace._heartbeats == beats[1:]
+    assert "stall_watch" in collectors and "gc_pause" in collectors
+    second.stop()
+    assert obs_trace._watch_holders == 0
+    assert _watch_threads() == []
+    assert obs_trace._heartbeats == []
+    assert _reaches(lambda: all(hb._handle.cancelled() for hb in beats),
+                    True)
+    assert "stall_watch" not in collectors and "gc_pause" not in collectors
+    assert obs_trace._gc_hook not in gc.callbacks
+    # a second stop() gives back nothing it does not hold
+    second.stop()
+    assert obs_trace._watch_holders == 0
+
+
 def test_gc_hook_takes_no_lock_and_skips_an_orphan_stop():
     hook = obs_trace.GcPauseHook()
     hook("stop", {"generation": 2})        # its start came before the hook

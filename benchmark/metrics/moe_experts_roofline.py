@@ -7,9 +7,12 @@ line, before ``=``; the operands' names are not looked at) holding one of
 the parts the configuration lists under ``moe_ops``: ``pio_moe_experts``
 (the program's Pallas kernel's custom calls, one a layer) or
 ``ragged-dot`` (what ``jax.lax.ragged_dot`` compiles to) — against the
-least time the chip could take for their own FLOPs and bytes
-(``benchmark.seqwork.moe_*``): the three tables of every layer once an
-execution of the forward, the routed rows in and out once a query."""
+least time the chip could take for the FLOPs and bytes the answers need
+(``benchmark.seqwork.moe_*``): the three tables of every layer but the
+last once an execution of the forward, and the rows routed in and out
+once a query; in the last layer, as in the head, the answered position
+alone (its ``top`` tables and rows), since no later layer reads the
+others."""
 
 
 def read(ctx):

@@ -2,9 +2,12 @@
 
 Device time of the serving program's executions in the trace, found by
 its XLA module name (the configuration's ``scoring_module``), against the
-least time the chip could take for the work ``benchmark.seqwork`` counts:
-each execution reads every weight once, and together they answered the
-queries that completed inside the traced window."""
+least time the chip could take for the work the answers need, as
+``benchmark.seqwork`` counts it: each execution reads every weight once
+(the last layer's expert tables only as far as the answered positions
+route), and together they answered the queries that completed inside the
+traced window. The last layer counts one position a query, as the head
+does: its other positions are read by nothing."""
 
 
 def read(ctx):

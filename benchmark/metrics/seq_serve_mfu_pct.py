@@ -1,8 +1,9 @@
 """Whole step: the share of the chip's peak the served sequence queries
-amount to (%): the model FLOPs of one window through the block and the
-head (``benchmark.seqwork.query_flops``: causal and windowed pairs only,
-no padding), times the queries answered in the traced window, over
-window × chips × peak FLOP/s."""
+amount to (%): the model FLOPs the answer to one window needs
+(``benchmark.seqwork.query_flops``: causal and windowed pairs only, no
+padding; the last layer, like the head, at the answered position alone,
+its K and V at every position), times the queries answered in the traced
+window, over window × chips × peak FLOP/s."""
 
 
 def read(ctx):

@@ -433,14 +433,13 @@ def check_answers(config: dict, mix: dict, seed: int, log: dict) -> tuple:
     limits = config["limits"]
     say(f"reference: {len(idx)} sampled answers compared in "
         f"{time.time() - t_ref:.1f} s")
-    compared = {
-        "score_err": {"value": numbers["score_err"],
-                      "limit": limits["score_err"]},
-        "rank_gap": {"value": numbers["rank_gap"],
-                     "limit": limits["rank_gap"]},
-        "malformed": {"value": numbers["malformed"], "limit": 0},
-        "compared": {"value": numbers["compared"], "limit": 1},
-    }
+    # every limit the configuration states; a number the reference did
+    # not reach (nothing compared) reads as the judge reads it, 0
+    compared = {name: {"value": numbers.get(name, 0.0), "limit": limit}
+                for name, limit in limits.items()}
+    compared.update(
+        malformed={"value": numbers["malformed"], "limit": 0},
+        compared={"value": numbers["compared"], "limit": 1})
     return bool(reference.judge(numbers, limits)), compared
 
 

@@ -5,10 +5,13 @@ finds (``JAX_PLATFORMS=cpu``). Not a chip run: its times mean nothing.
     python3 benchmark/tests/rehearse.py --workload W --seed N --trace 0|1
                                         [--fault NAME]
 
+The tiny size, the rate and the settings are the workload's
+configuration's own, under its ``rehearsal`` key.
+
 ``--fault`` breaks the timed path underneath the run, where the answer
 is produced, so a test can see ``correct`` come out false:
 ``alter_item`` swaps the two best items of every answer, ``alter_score``
-scales every score by 1.001.
+scales every score by 1.001 (the ALS engine's scoring program).
 """
 
 import argparse
@@ -20,19 +23,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
-
-TINY = {
-    "config": {
-        "n_users": 3000, "n_items": 2000, "rank": 64,
-        "algorithm": {"name": "als", "params": {
-            "rank": 64, "numIterations": 1, "lambda": 0.01}},
-    },
-    "rate": 200.0,
-    # the device path, as the chip takes it at the real size (a model
-    # this small would be served from the host mirror), and a short
-    # ladder so that the warm-up is quick
-    "env": {"PIO_HOST_SERVE_MAX_ELEMS": "0", "PIO_SERVE_MAX_BATCH": "8"},
-}
 
 
 def plant(fault: str) -> None:
@@ -69,7 +59,9 @@ def main() -> int:
     if args.fault:
         plant(args.fault)
     result, rc = run.run_cell(args.workload, args.seed, args.seconds,
-                              bool(args.trace), rehearsal=TINY)
+                              bool(args.trace),
+                              rehearsal=run.find_cell(args.workload)[
+                                  "config"]["rehearsal"])
     print(json.dumps(result), flush=True)
     if rc == run.LEFTOVER:
         sys.stderr.flush()

@@ -1,7 +1,12 @@
 """The plain reference against numpy, and its control: the reference one
 precision down, put in the program's place, has to come out not
-correct by the limits the configurations state."""
+correct by the limits each configuration states. Each configuration's
+file names its own control (its ``control`` key: the reference's
+precision that has to pass and the one below it that has to fail, on
+how many users), at the size of its ``rehearsal`` with the control's own
+keys laid over it."""
 
+import importlib
 import json
 import os
 
@@ -13,8 +18,13 @@ from benchmark.reference import als_topk
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CONFIGS = os.path.join(os.path.dirname(HERE), "configs")
-TINY = {"n_users": 900, "n_items": 5000, "rank": 2048,
-        "planted": {"rank": 16, "noise": 0.1}}
+
+
+def controlled(name: str) -> dict:
+    """The configuration at its control's size, with its ``control``."""
+    with open(os.path.join(CONFIGS, name)) as f:
+        cfg = json.load(f)
+    return {**cfg, **cfg["rehearsal"]["config"], **cfg["control"]["config"]}
 
 
 def limits():
@@ -23,6 +33,9 @@ def limits():
         with open(os.path.join(CONFIGS, name)) as f:
             out[name] = json.load(f)["limits"]
     return out
+
+
+TINY = controlled("als-msd-d2048.json")
 
 
 def test_rows_are_the_same_alone_and_in_the_table():
@@ -53,12 +66,18 @@ def test_reference_agrees_with_float64_numpy():
 @pytest.mark.parametrize("seed", [3, 2**31 + 11, 123456789])
 @pytest.mark.parametrize("name,lim", sorted(limits().items()))
 def test_control_one_precision_down_is_not_correct(name, lim, seed):
-    rows = np.random.default_rng(seed).choice(TINY["n_users"], 64,
+    cfg = controlled(name)
+    ctl = cfg["control"]
+    reference = importlib.import_module(
+        "benchmark.reference." + cfg["reference"])
+    rows = np.random.default_rng(seed).choice(cfg["n_users"], ctl["users"],
                                               replace=False)
-    sound = als_topk.control(TINY, seed, rows, 10, "highest")
-    assert als_topk.judge(sound, lim), sound
-    low = als_topk.control(TINY, seed, rows, 10, "high_emulated")
-    assert not als_topk.judge(low, lim), low
+    sound = reference.control(cfg, seed, rows, 10, ctl["sound"])
+    # at the sound precision the control is the reference itself
+    assert sound["score_err"] == 0.0 and sound["rank_gap"] == 0.0, sound
+    assert reference.judge(sound, lim), sound
+    low = reference.control(cfg, seed, rows, 10, ctl["low"])
+    assert not reference.judge(low, lim), low
 
 
 def test_malformed_answers_are_counted():
